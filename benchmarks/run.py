@@ -125,6 +125,10 @@ def main() -> None:
     args = ap.parse_args()
     wanted = args.only.split(",") if args.only else SUITES
 
+    from repro.launch.compile_cache import setup_compile_cache
+
+    setup_compile_cache()
+
     print("name,us_per_call,derived")
     for name in wanted:
         mod = __import__(f"benchmarks.bench_{name}", fromlist=["run"])

@@ -33,8 +33,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map as _shard_map
-
 from repro.core import index as index_mod
 from repro.core.docfilter import FilterView
 from repro.core.engine import (  # noqa: F401  (score_* re-exported for stage-level callers)
@@ -46,6 +44,7 @@ from repro.core.reduction import TopKResult
 from repro.core.types import IndexBuildConfig, WarpIndex, WarpSearchConfig
 from repro.core.warpselect import impute_mse, warp_select
 from repro.kernels import ops
+from repro.launch.mesh import make_mesh
 
 __all__ = [
     "ShardedWarpIndex",
@@ -110,9 +109,12 @@ def build_sharded_index(
     n_docs: int,
     n_shards: int,
     config: IndexBuildConfig = IndexBuildConfig(),
+    *,
+    mesh: jax.sharding.Mesh | None = None,
+    shard_axes: tuple[str, ...] = ("data",),
 ) -> ShardedWarpIndex:
     """Partition docs into contiguous, token-balanced ranges; build one
-    WarpIndex per shard; pad + stack."""
+    WarpIndex per shard; pad + place (see ``stack_shards``)."""
     emb = np.asarray(embeddings, np.float32)
     tdi = np.asarray(token_doc_ids, np.int32)
     n_tokens = emb.shape[0]
@@ -137,7 +139,22 @@ def build_sharded_index(
         shards.append(
             index_mod.build_index(emb[sel], tdi[sel] - lo, max(1, hi - lo), sub_cfg)
         )
-    return stack_shards(shards, doc_bounds[:-1], n_docs, n_tokens)
+    return stack_shards(
+        shards, doc_bounds[:-1], n_docs, n_tokens, mesh=mesh,
+        shard_axes=shard_axes,
+    )
+
+
+def _place_stacked(per_shard: list, sharding) -> jax.Array:
+    """Stack per-shard arrays along a new leading axis directly into
+    ``sharding``: each shard's slice is put on the device that owns it, so
+    no device ever holds the whole stack."""
+    shape = (len(per_shard),) + tuple(per_shard[0].shape)
+    bufs = []
+    for dev, idx in sharding.addressable_devices_indices_map(shape).items():
+        s = idx[0].start or 0
+        bufs.append(jax.device_put(per_shard[s][None], dev))
+    return jax.make_array_from_single_device_arrays(shape, sharding, bufs)
 
 
 def stack_shards(
@@ -145,8 +162,19 @@ def stack_shards(
     doc_start,
     n_docs: int,
     n_tokens_total: int,
+    *,
+    mesh: jax.sharding.Mesh | None = None,
+    shard_axes: tuple[str, ...] = ("data",),
 ) -> ShardedWarpIndex:
     """Pad per-shard ``WarpIndex``es to common geometry and stack them.
+
+    With a ``mesh`` every stacked array is placed as
+    ``NamedSharding(mesh, P(shard_axes))``: shard s's arrays go straight to
+    the device that serves shard s, the layout the sharded search expects,
+    so the first search moves nothing. Without one, and with exactly
+    ``n_shards`` devices, the mesh is the ``("data",)`` mesh the
+    ``Retriever`` would build; otherwise the stack is built on the default
+    device.
 
     ``doc_start[s]`` is the global id of shard ``s``'s first document.
     Exposed separately from ``build_sharded_index`` so shard stacks can be
@@ -162,7 +190,10 @@ def stack_shards(
         if pad == 0:
             return arr
         cfg = [(0, pad)] + [(0, 0)] * (arr.ndim - 1)
-        return jnp.pad(arr, cfg, constant_values=fill)
+        # Host arrays (a chunked build's output) pad on the host, so they
+        # reach a device only once, as their own shard.
+        xp = np if isinstance(arr, np.ndarray) else jnp
+        return xp.pad(arr, cfg, constant_values=fill)
 
     cents, codes, tdis, offs, sizes, weights = [], [], [], [], [], []
     for s in shards:
@@ -176,14 +207,22 @@ def stack_shards(
         sizes.append(pad_to(s.cluster_sizes, c_max, 0))
         weights.append(s.bucket_weights)
 
+    if mesh is None and n_shards > 1 and len(jax.devices()) == n_shards:
+        mesh = make_mesh((n_shards,), ("data",))
+        shard_axes = ("data",)
+    if mesh is None:
+        stack = jnp.stack
+    else:
+        sharding = jax.sharding.NamedSharding(mesh, P(shard_axes))
+        stack = functools.partial(_place_stacked, sharding=sharding)
     return ShardedWarpIndex(
-        centroids=jnp.stack(cents),
-        packed_codes=jnp.stack(codes),
-        token_doc_ids=jnp.stack(tdis),
-        cluster_offsets=jnp.stack(offs),
-        cluster_sizes=jnp.stack(sizes),
-        bucket_weights=jnp.stack(weights),
-        doc_start=jnp.asarray(np.asarray(doc_start)[:n_shards], jnp.int32),
+        centroids=stack(cents),
+        packed_codes=stack(codes),
+        token_doc_ids=stack(tdis),
+        cluster_offsets=stack(offs),
+        cluster_sizes=stack(sizes),
+        bucket_weights=stack(weights),
+        doc_start=stack(list(np.asarray(doc_start, np.int32)[:n_shards])),
         dim=shards[0].dim,
         nbits=shards[0].nbits,
         cap=cap,
@@ -334,7 +373,7 @@ def make_sharded_search_fn(
     else:
         body = local_search
         in_specs = (idx_spec, P(), P())
-    fn = _shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=in_specs,
@@ -435,7 +474,7 @@ def sharded_search(
     ``FilterView``.
     """
     if mesh is None:
-        mesh = jax.make_mesh((sidx.n_shards,), ("data",))
+        mesh = make_mesh((sidx.n_shards,), ("data",))
         shard_axes = ("data",)
     config = resolve_sharded_config(sidx, config)
     if qmask is None:

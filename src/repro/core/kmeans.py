@@ -15,15 +15,27 @@ import jax.numpy as jnp
 
 __all__ = ["spherical_kmeans", "assign_clusters", "l2_normalize"]
 
+# Largest [block, n_centroids] f32 score matrix one assignment step holds
+# (2^26 entries = 256 MiB): at LoTTE scale (2^17 centroids) a fixed block
+# of 65536 points would need a 32 GiB score matrix.
+SCORE_BLOCK_ELEMS = 1 << 26
+
 
 def l2_normalize(x: jax.Array, eps: float = 1e-12) -> jax.Array:
     return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + eps)
 
 
 @functools.partial(jax.jit, static_argnames=("block",))
-def assign_clusters(points: jax.Array, centroids: jax.Array, *, block: int = 65536) -> jax.Array:
-    """argmax_c <x, c> for every point, blocked to bound peak memory."""
+def assign_clusters(
+    points: jax.Array, centroids: jax.Array, *, block: int | None = None
+) -> jax.Array:
+    """argmax_c <x, c> for every point, blocked to bound peak memory.
+
+    The default block keeps each step's score matrix within
+    ``SCORE_BLOCK_ELEMS`` (at most 65536 points)."""
     n = points.shape[0]
+    if block is None:
+        block = max(1, min(65536, SCORE_BLOCK_ELEMS // centroids.shape[0]))
     pad = (-n) % block
     pts = jnp.pad(points, ((0, pad), (0, 0)))
 
@@ -37,7 +49,7 @@ def assign_clusters(points: jax.Array, centroids: jax.Array, *, block: int = 655
 @functools.partial(jax.jit, static_argnames=("k",))
 def _lloyd_step(points: jax.Array, centroids: jax.Array, key: jax.Array, *, k: int):
     """One spherical Lloyd iteration; empty clusters re-seeded from random points."""
-    assign = jnp.argmax(points @ centroids.T, axis=-1)
+    assign = assign_clusters(points, centroids)
     sums = jax.ops.segment_sum(points, assign, num_segments=k)
     counts = jax.ops.segment_sum(
         jnp.ones((points.shape[0],), jnp.float32), assign, num_segments=k

@@ -70,6 +70,7 @@ from repro.core.index import build_index
 from repro.core.reduction import TopKResult
 from repro.core.types import IndexBuildConfig, WarpIndex, WarpSearchConfig
 from repro.kernels import ops
+from repro.launch.mesh import make_mesh
 from repro.obs import STATE as _OBS
 
 __all__ = ["Retriever", "SearchPlan", "K_LADDER", "ladder_rung", "laddered_config"]
@@ -598,7 +599,7 @@ class Retriever:
             raise ValueError("mesh= does not apply to a SegmentedWarpIndex")
         if self.is_sharded:
             if mesh is None:
-                mesh = jax.make_mesh((index.n_shards,), ("data",))
+                mesh = make_mesh((index.n_shards,), ("data",))
                 self.shard_axes = ("data",)
             mesh_size = 1
             for ax in self.shard_axes:
@@ -635,7 +636,8 @@ class Retriever:
             index = build_index(embeddings, token_doc_ids, n_docs, index_cfg)
             return cls(index)
         sidx = dist.build_sharded_index(
-            embeddings, token_doc_ids, n_docs, n_shards, index_cfg
+            embeddings, token_doc_ids, n_docs, n_shards, index_cfg,
+            mesh=mesh, shard_axes=shard_axes,
         )
         return cls(sidx, mesh=mesh, shard_axes=shard_axes)
 

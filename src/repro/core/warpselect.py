@@ -86,7 +86,13 @@ def warp_select(
     """
     kk = max(nprobe, k_impute)
     s_cq = q @ centroids.T  # [Q, C]
+    # The barrier keeps XLA from re-deriving the [:nprobe] slices below as
+    # a second top-k over all C centroids, which the TPU compiler lowers
+    # far more slowly (~15 s per program at 2^17 centroids); the values
+    # are the same either way.
     top_scores, top_cids = jax.lax.top_k(s_cq, kk)  # [Q, kk] desc
+    top_scores = jax.lax.optimization_barrier(top_scores)
+    top_cids = jax.lax.optimization_barrier(top_cids)
     top_sizes = cluster_sizes[top_cids]  # [Q, kk]
     mse = impute_mse(top_scores, top_sizes, t_prime, qmask)
     return WarpSelectOut(

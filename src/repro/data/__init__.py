@@ -1,3 +1,15 @@
-from repro.data.synth import SynthCorpus, make_corpus, make_queries
+from repro.data.synth import (
+    StreamedCorpus,
+    SynthCorpus,
+    make_corpus,
+    make_queries,
+    make_streamed_corpus,
+)
 
-__all__ = ["SynthCorpus", "make_corpus", "make_queries"]
+__all__ = [
+    "StreamedCorpus",
+    "SynthCorpus",
+    "make_corpus",
+    "make_queries",
+    "make_streamed_corpus",
+]

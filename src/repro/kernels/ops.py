@@ -1,8 +1,8 @@
 """Public jit'd wrappers around the Pallas kernels.
 
 Each op pads inputs to the kernel's tiling, runs interpret=True off-TPU
-(this container is CPU-only; interpret mode executes the kernel body in
-Python for correctness validation), and slices the result back. Callers can
+(interpret mode executes the kernel body in Python for correctness
+validation), and slices the result back. Callers can
 force the pure-jnp reference with ``use_kernel=False``.
 """
 
@@ -21,6 +21,7 @@ from repro.kernels.fused_gather_score import (
     DEFAULT_BUFFERING,
     DEFAULT_RAGGED_TILE_C,
     DEFAULT_TILE_C,
+    LANES,
     fused_gather_score_kernel_call,
     ragged_fused_gather_score_kernel_call,
     validate_tile_c,
@@ -51,6 +52,12 @@ def _fault_kernel_call(op: str) -> None:
     one attribute check."""
     if _FAULTS.plan is not None:
         _FAULTS.plan.check("engine.kernel_call", op=op)
+
+
+def _lane_tiled(pb: int) -> bool:
+    """Whether packed rows of ``pb`` bytes tile the fused kernels'
+    128-lane view of the codes (``fused_gather_score.lane_rows``)."""
+    return pb <= LANES and LANES % pb == 0
 
 
 def _round_up(x: int, m: int) -> int:
@@ -278,6 +285,7 @@ def fused_gather_selective_sum(
         or nbits == 8  # 256 select-accumulate unrolls: ref lowers better
         or cap == 0
         or n_tokens < tile  # index smaller than one code tile
+        or not _lane_tiled(packed_codes.shape[-1])
     ):
         if probe != "full":
             raise ValueError(
@@ -365,6 +373,7 @@ def ragged_fused_gather_selective_sum(
         or nbits == 8  # 256 select-accumulate unrolls: ref lowers better
         or n_tokens < tile_c  # index smaller than one code tile
         or row0.shape[0] == 0
+        or not _lane_tiled(packed_codes.shape[-1])
     ):
         if probe != "full":
             raise ValueError(
@@ -437,6 +446,7 @@ def segmented_ragged_fused_gather_selective_sum(
         not use_kernel
         or nbits == 8  # 256 select-accumulate unrolls: ref lowers better
         or row0.shape[0] == 0
+        or not _lane_tiled(packed_list[0].shape[-1])
     ):
         return ref.segmented_ragged_fused_gather_score(
             packed_list, row0, nvalid, seg, qtok, pscore, v,

@@ -23,7 +23,6 @@ import jax
 
 from repro.configs.registry import all_cells, get_arch
 from repro.launch.hlo_cost import analyze_hlo
-from repro.launch.mesh import set_mesh
 from repro.launch.mesh import make_production_mesh
 from repro.launch.roofline import model_flops, roofline_terms
 from repro.launch.sharding import tree_named_sharding
@@ -63,7 +62,7 @@ def run_cell(arch_name: str, shape: str, multi_pod: bool, *, verbose: bool = Tru
         tree_named_sharding(input_ps, mesh),
     )
 
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         if needs_mesh:
             # shard_map fns carry their own specs; in_shardings constrain args.
             lowered = jax.jit(step).lower(state, inputs)

@@ -9,13 +9,11 @@ from __future__ import annotations
 
 import jax
 
-from repro.compat import AxisType as _AxisType
-from repro.compat import set_mesh
+from jax.sharding import AxisType
 
 __all__ = [
     "make_production_mesh",
     "make_mesh",
-    "set_mesh",
     "data_axes",
     "MODEL_AXIS",
 ]
@@ -24,10 +22,9 @@ MODEL_AXIS = "model"
 
 
 def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]) -> jax.sharding.Mesh:
-    """jax.make_mesh with explicit Auto axis types (silences the 0.9 change)."""
-    if _AxisType is not None:
-        return jax.make_mesh(shape, axes, axis_types=(_AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with Auto axis types: the installed JAX defaults
+    to Explicit axes, and every sharding rule here is written for Auto."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:

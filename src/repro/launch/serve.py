@@ -38,6 +38,7 @@ import numpy as np
 from repro import obs
 from repro.core import IndexBuildConfig, Retriever, WarpSearchConfig, index_stats
 from repro.data import make_corpus, make_queries
+from repro.launch.compile_cache import setup_compile_cache
 from repro.serving import AdmissionPolicy, BatchPolicy, Overloaded, RetrievalServer
 
 
@@ -168,6 +169,7 @@ def main() -> None:
                     help="periodic summary flush interval for --traffic "
                          "poisson, on the server's clock (0 disables)")
     args = ap.parse_args()
+    setup_compile_cache()
 
     if args.trace_out:
         # The tracer shares the server's clock (time.monotonic) so the

@@ -21,7 +21,6 @@ import math
 import jax
 import jax.numpy as jnp
 
-from repro.compat import shard_map as _shard_map
 
 from repro.models.layers import dense_init
 
@@ -154,7 +153,7 @@ def _moe_apply_local(params: dict, cfg: MoEConfig, x: jax.Array):
         aux = jax.lax.pmean(jax.lax.pmean(aux, model), data)
         return y.astype(xl.dtype), aux
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         local,
         in_specs=(
             P(data, None),

@@ -28,10 +28,23 @@ except ImportError:
     _stub.install()
 
 
-def _on_tpu() -> bool:
+def _platforms_exclude_tpu() -> bool:
+    """Whether ``JAX_PLATFORMS`` already rules the TPU out. Read from the
+    environment so collection never starts a JAX backend: on a host with
+    the chip, a collection hook that did would make every xdist worker
+    claim the TPU."""
+    platforms = os.environ.get("JAX_PLATFORMS", "")
+    return bool(platforms) and "tpu" not in platforms.split(",")
+
+
+@pytest.fixture
+def _requires_tpu_backend():
+    """Run-time half of the ``requires_tpu`` skip, for when the
+    environment leaves the platform open: asks JAX inside the test."""
     import jax
 
-    return jax.default_backend() == "tpu"
+    if jax.default_backend() != "tpu":
+        pytest.skip("requires a real TPU backend")
 
 
 def pytest_addoption(parser):
@@ -65,7 +78,7 @@ def pytest_configure(config):
 
 
 def pytest_collection_modifyitems(config, items):
-    tpu = None
+    no_tpu = _platforms_exclude_tpu()
     run_slow_build = config.getoption("--slow-build")
     for item in items:
         if not run_slow_build and item.get_closest_marker("slow_build"):
@@ -75,12 +88,12 @@ def pytest_collection_modifyitems(config, items):
         marker = item.get_closest_marker("tpu_kernel")
         if marker is None or not marker.kwargs.get("requires_tpu", False):
             continue
-        if tpu is None:
-            tpu = _on_tpu()
-        if not tpu:
+        if no_tpu:
             item.add_marker(
                 pytest.mark.skip(reason="requires a real TPU backend")
             )
+        else:
+            item.add_marker(pytest.mark.usefixtures("_requires_tpu_backend"))
 
 
 @pytest.fixture(scope="session")

@@ -91,3 +91,44 @@ def test_sharded_multi_device_subprocess():
     )
     assert out.returncode == 0, out.stderr[-2000:]
     assert "OK" in out.stdout
+
+
+PLACEMENT_SCRIPT = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import numpy as np, jax, jax.numpy as jnp
+from repro.core import IndexBuildConfig, Retriever, WarpSearchConfig, build_sharded_index
+from repro.core.distributed import stack_shards
+from repro.data import make_corpus, make_queries
+
+corpus = make_corpus(n_docs=240, mean_doc_len=14, seed=0)
+sidx = build_sharded_index(corpus.emb, corpus.token_doc_ids, corpus.n_docs, 4,
+                           IndexBuildConfig(n_centroids=16, kmeans_iters=2))
+devs = jax.devices()
+for arr in (sidx.packed_codes, sidx.centroids, sidx.doc_start):
+    shards = arr.addressable_shards
+    assert sorted(d.id for d in {s.device for s in shards}) == [0, 1, 2, 3]
+    assert all(s.data.shape[0] == 1 for s in shards)
+    assert all(s.index[0].start == s.device.id for s in shards)
+q, qmask, _ = make_queries(corpus, n_queries=2, seed=1)
+cfg = WarpSearchConfig(nprobe=8, k=10)
+placed = Retriever.from_index(sidx).plan(cfg).retrieve(q[0], qmask[0])
+# The same shards stacked on one device answer identically.
+stacked = jax.tree.map(lambda a: jnp.asarray(np.asarray(a)), sidx)
+again = Retriever.from_index(stacked).plan(cfg).retrieve(q[0], qmask[0])
+np.testing.assert_array_equal(np.asarray(placed.doc_ids), np.asarray(again.doc_ids))
+print("OK")
+"""
+
+
+def test_four_shard_placement_one_shard_per_device_subprocess():
+    """A 4-shard build on 4 devices puts shard s on device s (never the
+    whole stack on device 0) and answers like a single-device stack."""
+    env = dict(os.environ, PYTHONPATH="src")
+    out = subprocess.run(
+        [sys.executable, "-c", PLACEMENT_SCRIPT],
+        capture_output=True, text=True, env=env, timeout=600,
+        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    )
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert "OK" in out.stdout

@@ -7,7 +7,7 @@ import pytest
 from hypothesis import given, settings
 from hypothesis import strategies as st
 
-from repro.launch.mesh import make_mesh, set_mesh
+from repro.launch.mesh import make_mesh
 from repro.models.moe import MoEConfig, moe_apply, moe_init
 
 
@@ -18,7 +18,7 @@ def test_local_dispatch_matches_global_single_device():
     x = jax.random.normal(jax.random.PRNGKey(1), (24, 32))
     y_g, aux_g = moe_apply(p, cfg_g, x)
     mesh = make_mesh((1, 1), ("data", "model"))
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         y_l, aux_l = jax.jit(lambda p, x: moe_apply(p, cfg_l, x))(p, x)
     np.testing.assert_allclose(np.asarray(y_g), np.asarray(y_l), rtol=2e-5, atol=2e-5)
     np.testing.assert_allclose(float(aux_g), float(aux_l), rtol=1e-4)
