@@ -33,7 +33,7 @@ import jax.numpy as jnp
 from repro.core.docfilter import DocFilter, FilterView, resolve_local
 from repro.core.reduction import TopKResult, two_stage_reduce
 from repro.core.types import WarpIndex, WarpSearchConfig
-from repro.core.warpselect import warp_select
+from repro.core.warpselect import SCOPE_SELECT, warp_select
 from repro.core.worklist import (
     bucket_ladder,
     build_tile_worklist,
@@ -44,6 +44,7 @@ from repro.core.worklist import (
 from repro.kernels import ops
 
 __all__ = [
+    "STAGE_SCOPES",
     "search",
     "search_batch",
     "gather_candidates",
@@ -58,10 +59,15 @@ __all__ = [
     "score_and_reduce",
     "select_probes",
     "finish_from_probes",
-    "score_from_probes",
-    "reduce_from_scored",
-    "kernel_dma_compute_split",
 ]
+
+# Named scopes of the three pipeline stages. Every op a stage emits
+# carries its scope in the HLO ``op_name``, so a device trace of the one
+# compiled retrieve program splits its time by stage. The ``warp.``
+# prefix keeps them apart from primitive names (``reduce`` is one).
+SCOPE_GATHER_SCORE = "warp.gather_score"
+SCOPE_REDUCE = "warp.reduce"
+STAGE_SCOPES = (SCOPE_SELECT, SCOPE_GATHER_SCORE, SCOPE_REDUCE)
 
 
 def resolve_tile_fields(
@@ -455,35 +461,39 @@ def score_candidates(
     ``reduce_candidates`` (the two-stage reduction masks filtered docs'
     totals to -inf), which is exact because imputation never depends on
     which candidates survive.
-    """
-    qm = q.shape[0]
-    if config.layout == "ragged":
-        if probe_sizes is None:
-            probe_sizes = index.cluster_sizes[probe_cids]
-        probe_sizes = jnp.where(qmask[:, None], probe_sizes, 0)
-        if dfilter is not None:
-            probe_sizes = filtered_probe_sizes(
-                probe_sizes, probe_cids, dfilter.cluster_live
-            )
-        scores, doc_ids, qtok, valid = ragged_flat_candidates(
-            index, q, probe_scores, probe_cids, config, probe_sizes
-        )
-        return doc_ids, qtok, scores, valid & qmask[qtok]
 
-    p, cap = config.nprobe, index.cap
-    cand_scores, doc_ids, valid = score_probed_clusters(
-        index, q, probe_scores, probe_cids, config
-    )
-    valid = valid & qmask[:, None, None]
-    qtok = jnp.broadcast_to(
-        jnp.arange(qm, dtype=jnp.int32)[:, None, None], (qm, p, cap)
-    )
-    return (
-        doc_ids.reshape(-1),
-        qtok.reshape(-1),
-        cand_scores.reshape(-1),
-        valid.reshape(-1),
-    )
+    Every op of the stage carries the ``warp.gather_score`` scope in its
+    HLO ``op_name``, so a device trace attributes its time to this stage.
+    """
+    with jax.named_scope(SCOPE_GATHER_SCORE):
+        qm = q.shape[0]
+        if config.layout == "ragged":
+            if probe_sizes is None:
+                probe_sizes = index.cluster_sizes[probe_cids]
+            probe_sizes = jnp.where(qmask[:, None], probe_sizes, 0)
+            if dfilter is not None:
+                probe_sizes = filtered_probe_sizes(
+                    probe_sizes, probe_cids, dfilter.cluster_live
+                )
+            scores, doc_ids, qtok, valid = ragged_flat_candidates(
+                index, q, probe_scores, probe_cids, config, probe_sizes
+            )
+            return doc_ids, qtok, scores, valid & qmask[qtok]
+
+        p, cap = config.nprobe, index.cap
+        cand_scores, doc_ids, valid = score_probed_clusters(
+            index, q, probe_scores, probe_cids, config
+        )
+        valid = valid & qmask[:, None, None]
+        qtok = jnp.broadcast_to(
+            jnp.arange(qm, dtype=jnp.int32)[:, None, None], (qm, p, cap)
+        )
+        return (
+            doc_ids.reshape(-1),
+            qtok.reshape(-1),
+            cand_scores.reshape(-1),
+            valid.reshape(-1),
+        )
 
 
 def reduce_candidates(
@@ -504,20 +514,22 @@ def reduce_candidates(
     bound fewer than ``k`` slots on skew-free tiny indexes, so that
     layout pads the reduction to k (all-invalid slots). ``dfilter``'s
     doc mask (local id space of THIS index) masks filtered documents to
-    -inf before top-k — the exactness point of the filter pushdown."""
-    return two_stage_reduce(
-        doc_ids,
-        qtok,
-        scores,
-        valid,
-        mse,
-        dfilter.doc_mask if dfilter is not None else None,
-        q_max=q_max,
-        k=config.k,
-        impl=config.reduce_impl,
-        n_docs=index.n_docs or None,
-        pad_to_k=config.layout == "ragged",
-    )
+    -inf before top-k — the exactness point of the filter pushdown. Its
+    ops carry the ``warp.reduce`` scope."""
+    with jax.named_scope(SCOPE_REDUCE):
+        return two_stage_reduce(
+            doc_ids,
+            qtok,
+            scores,
+            valid,
+            mse,
+            dfilter.doc_mask if dfilter is not None else None,
+            q_max=q_max,
+            k=config.k,
+            impl=config.reduce_impl,
+            n_docs=index.n_docs or None,
+            pad_to_k=config.layout == "ragged",
+        )
 
 
 def score_and_reduce(
@@ -535,8 +547,7 @@ def score_and_reduce(
     """Stages 2+3 of the pipeline: implicit decompression over the probe
     set, then the two-stage reduction to top-k — the composition of
     ``score_candidates`` and ``reduce_candidates`` (one op sequence; the
-    split exists so the traced path can fence and time the stages
-    separately without a second pipeline definition).
+    sharded path runs the two halves around its cross-shard merge).
 
     ``mse`` is the per-query-token missing similarity estimate — locally
     imputed by ``warp_select`` on the single-device path, globally merged
@@ -607,162 +618,6 @@ def finish_from_probes(
         )
 
     return jax.vmap(one)(q, qmask, sel) if query_batch else one(q, qmask, sel)
-
-
-@functools.partial(jax.jit, static_argnames=("config", "query_batch"))
-def score_from_probes(
-    index, q, qmask, sel, config, query_batch: bool = False, dfilter=None
-):
-    """Stage 2 from a precomputed WARP_SELECT output, jit'd per config.
-
-    Returns the flat candidate stream ``(doc_ids, qtok, scores, valid)``
-    (leading [B] axis under ``query_batch``). ``score_from_probes`` ->
-    ``reduce_from_scored`` composes to exactly ``finish_from_probes``
-    (same stage functions, same order), so the traced/profiled execution
-    path (``repro.obs``) that fences between the two stages inherits the
-    bit-parity guarantees of the fused dispatch.
-    """
-
-    def one(q_i, m_i, sel_i):
-        return score_candidates(
-            index, q_i, m_i, sel_i.probe_scores, sel_i.probe_cids, config,
-            probe_sizes=sel_i.probe_sizes, dfilter=dfilter,
-        )
-
-    return jax.vmap(one)(q, qmask, sel) if query_batch else one(q, qmask, sel)
-
-
-@functools.partial(jax.jit, static_argnames=("config", "query_batch"))
-def reduce_from_scored(
-    index, scored, mse, config, query_batch: bool = False, dfilter=None
-) -> TopKResult:
-    """Stage 3 from ``score_from_probes`` output, jit'd per config.
-
-    ``mse`` is the WARP_SELECT missing-similarity estimate (f32[Q], or
-    f32[B, Q] under ``query_batch``); its trailing axis is the padded
-    query length the reduction scatters over.
-    """
-    q_max = mse.shape[-1]
-
-    def one(sc_i, m_i):
-        doc_ids, qtok, scores, valid = sc_i
-        return reduce_candidates(
-            index, doc_ids, qtok, scores, valid, m_i, config, q_max=q_max,
-            dfilter=dfilter,
-        )
-
-    return jax.vmap(one)(scored, mse) if query_batch else one(scored, mse)
-
-
-def kernel_dma_compute_split(
-    index: WarpIndex,
-    q: jax.Array,
-    qmask: jax.Array,
-    sel,
-    config: WarpSearchConfig,
-    *,
-    warmup: int = 1,
-    iters: int = 2,
-) -> dict:
-    """DMA/compute carve-out timing of the fused gather-score kernel at
-    this query's actual probe set — the PR 6 ``probe`` measurement hooks
-    surfaced per-request for the tracing profiler.
-
-    Re-times the stage-2 kernel with ``probe="full"`` and ``probe="dma"``
-    (and ``probe="compute"`` under double buffering; single buffering
-    derives compute as full - dma), returning ``{"dma_ms", "compute_ms",
-    "overlap_frac", ...}`` median-of-``iters``. Returns ``{}`` whenever
-    the Pallas kernel is not on this config's path (materialize gather,
-    reference executor, nbits=8, or an index smaller than one tile) —
-    the reference has no halves to carve. Each call re-runs the kernel
-    ~3x(warmup+iters) times: armed only by ``obs.set_kernel_probes``.
-
-    Batched inputs ([B, Q, D]) are probed at batch element 0 — one
-    representative carve-out, not B of them.
-    """
-    from repro.obs.metrics import time_fn as _time_fn
-
-    if config.gather != "fused" or not config.wants_kernel:
-        return {}
-    if index.nbits == 8 or index.cap == 0:
-        return {}
-    if q.ndim == 3:
-        q = q[0]
-        qmask = qmask[0]
-        sel = jax.tree_util.tree_map(lambda a: a[0], sel)
-    ragged = config.layout == "ragged"
-    tile = ops.resolve_tile_c(
-        index.cap, config.tile_c, layout="ragged" if ragged else "dense"
-    )
-    if index.n_tokens < tile:
-        return {}
-    buffering = (
-        config.buffering if config.buffering in ("single", "double")
-        else ops.DEFAULT_BUFFERING
-    )
-    v = q[:, :, None] * index.bucket_weights[None, None, :]
-
-    if ragged:
-        bound = config.worklist_tiles
-        if bound is None:
-            return {}
-        starts = index.cluster_offsets[sel.probe_cids].astype(jnp.int32)
-        sizes = jnp.where(
-            qmask[:, None], sel.probe_sizes, 0
-        ).astype(jnp.int32)
-        wl = build_tile_worklist(
-            starts, sizes, sel.probe_scores, tile_c=tile,
-            tiles_per_qtoken=bound,
-        )
-        if wl.row0.shape[0] == 0:
-            return {}
-
-        def make(probe):
-            @functools.partial(jax.jit, static_argnames=("probe",))
-            def f(row0, nvalid, qtok, pscore, vv, probe=probe):
-                return ops.ragged_fused_gather_selective_sum(
-                    index.packed_codes, row0, nvalid, qtok, pscore, vv,
-                    nbits=index.nbits, dim=index.dim, tile_c=tile,
-                    n_tokens=index.n_tokens, use_kernel=True,
-                    buffering=buffering, probe=probe,
-                )
-
-            return lambda: f(wl.row0, wl.nvalid, wl.qtok, wl.pscore, v)
-    else:
-
-        def make(probe):
-            @functools.partial(jax.jit, static_argnames=("probe",))
-            def f(cids, pscores, vv, probe=probe):
-                return ops.fused_gather_selective_sum(
-                    index.packed_codes, index.cluster_offsets,
-                    index.cluster_sizes, cids, pscores, vv,
-                    nbits=index.nbits, dim=index.dim, cap=index.cap,
-                    n_tokens=index.n_tokens, use_kernel=True, tile_c=tile,
-                    buffering=buffering, probe=probe,
-                )
-
-            return lambda: f(sel.probe_cids, sel.probe_scores, v)
-
-    kw = dict(warmup=warmup, iters=iters, sync=jax.block_until_ready)
-    t_full = _time_fn(make("full"), **kw)
-    t_dma = _time_fn(make("dma"), **kw)
-    if buffering == "double":
-        t_comp = _time_fn(make("compute"), **kw)
-    else:
-        t_comp = max(t_full - t_dma, 0.0)
-    denom = min(t_dma, t_comp)
-    overlap = (
-        max(0.0, min(1.0, (t_dma + t_comp - t_full) / denom))
-        if denom > 0 else 0.0
-    )
-    return {
-        "kernel_full_ms": round(t_full * 1e3, 4),
-        "dma_ms": round(t_dma * 1e3, 4),
-        "compute_ms": round(t_comp * 1e3, 4),
-        "overlap_frac": round(overlap, 4),
-        "probe_tile_c": tile,
-        "probe_buffering": buffering,
-    }
 
 
 @functools.partial(jax.jit, static_argnames=("config",))

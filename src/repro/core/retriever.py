@@ -135,33 +135,6 @@ def laddered_config(
     return dataclasses.replace(base, **kw)
 
 
-class _StagedLocal:
-    """Stage-split execution recipe for the traced path (local plans).
-
-    The traced dispatcher (``SearchPlan._run_traced``) re-composes the
-    pipeline from the engine's staged jit entry points — ``select_probes``
-    -> (adaptive bucket pick) -> ``score_from_probes`` ->
-    ``reduce_from_scored`` — fencing between stages so each span's
-    duration means exactly that stage. ``pick`` is the host-side adaptive
-    bucket probe over WARP_SELECT output (None on non-adaptive plans);
-    ``cfg_at(bucket)`` the run config at a forced rung (identity on
-    non-adaptive plans). Built only for local (non-sharded,
-    non-segmented) indexes — the distributed paths run their stages under
-    ``shard_map``/per-segment merges and trace as one engine span.
-    """
-
-    __slots__ = ("base_cfg", "pick", "cfg_at", "fview")
-
-    def __init__(self, base_cfg, pick, cfg_at, fview=None):
-        self.base_cfg = base_cfg
-        self.pick = pick
-        self.cfg_at = cfg_at
-        # Resolved FilterView of a filtered plan (None unfiltered): the
-        # traced stages must thread the same filter the untraced dispatch
-        # runs with, or traced results would silently ignore it.
-        self.fview = fview
-
-
 @dataclasses.dataclass(frozen=True, eq=False)
 class SearchPlan:
     """A validated, compiled search pipeline bound to one index + config.
@@ -188,9 +161,6 @@ class SearchPlan:
     # Forced-rung batch dispatch (None on non-adaptive plans):
     # bucket -> compiled (index, q, qmask) -> TopKResult at that rung.
     _batch_at: Any = dataclasses.field(repr=False, default=None)
-    # Stage-split recipe for the traced path (None on sharded/segmented
-    # plans, which trace as a single engine span) — see ``_StagedLocal``.
-    _staged: Any = dataclasses.field(repr=False, default=None)
     # Executor fallback (kernel plans only): a zero-arg factory compiling
     # the same pipeline with executor="reference" (bit-identical results),
     # invoked when the kernel path fails at warmup or dispatch.
@@ -216,18 +186,14 @@ class SearchPlan:
         q = jnp.asarray(q, jnp.float32)
         if qmask is None:
             qmask = jnp.ones((q.shape[0],), bool)
-        return self._dispatch(
-            q, jnp.asarray(qmask, bool), kind="single", query_batch=False,
-        )
+        return self._dispatch(q, jnp.asarray(qmask, bool), kind="single")
 
     def retrieve_batch(self, q: jax.Array, qmask: jax.Array | None = None) -> TopKResult:
         """Query batch: q f32[B, Q, D] -> TopKResult with leading batch dim."""
         q = jnp.asarray(q, jnp.float32)
         if qmask is None:
             qmask = jnp.ones(q.shape[:2], bool)
-        return self._dispatch(
-            q, jnp.asarray(qmask, bool), kind="batch", query_batch=True,
-        )
+        return self._dispatch(q, jnp.asarray(qmask, bool), kind="batch")
 
     # ---- executor fallback ----
     @property
@@ -286,17 +252,19 @@ class SearchPlan:
             return self._batch
         return self._batch_at(bucket)
 
-    def _dispatch(
-        self, q, qmask, *, kind: str, query_batch: bool, bucket=None
-    ) -> TopKResult:
+    def _dispatch(self, q, qmask, *, kind: str, bucket=None) -> TopKResult:
         """Observability-aware dispatch (``repro.obs.STATE``).
 
-        Disabled (the default): two attribute checks, then straight into
-        the compiled callable — the near-zero-cost path BENCH_obs.json
-        bounds. Metrics-only: the same callable timed into the
-        ``warp_retrieve_seconds`` histogram (one ``block_until_ready`` —
-        a latency metric over async dispatch would time the enqueue).
-        Tracing: the stage-split path (``_run_traced``).
+        Every state runs the same compiled callable; nothing about the
+        program changes with observability, so results are bit-identical.
+        The call sits in one ``retrieve`` span (``obs.span``: a tracer
+        span when a tracer is installed, a profiler annotation while a
+        profiler session records; nothing otherwise), without a fence:
+        a retrieve only enqueues, and the stage times come from the
+        device trace, where every op carries its stage's ``warp.*``
+        scope (``engine.STAGE_SCOPES``). Metrics-only adds the
+        ``warp_retrieve_seconds`` histogram, which blocks once on the
+        result (a latency over async dispatch would time the enqueue).
 
         Kernel plans get one safety net on top: a failure escaping the
         compiled callable demotes the plan to the reference executor
@@ -305,9 +273,7 @@ class SearchPlan:
         specific shape/bucket.
         """
         try:
-            return self._dispatch_modes(
-                q, qmask, kind=kind, query_batch=query_batch, bucket=bucket
-            )
+            return self._dispatch_modes(q, qmask, kind=kind, bucket=bucket)
         except Exception as e:  # noqa: BLE001
             if (
                 self.config.executor != "kernel"
@@ -316,25 +282,22 @@ class SearchPlan:
             ):
                 raise
             self._activate_fallback(e)
-            return self._dispatch_modes(
-                q, qmask, kind=kind, query_batch=query_batch, bucket=bucket
-            )
+            return self._dispatch_modes(q, qmask, kind=kind, bucket=bucket)
 
-    def _dispatch_modes(
-        self, q, qmask, *, kind: str, query_batch: bool, bucket=None
-    ) -> TopKResult:
-        if _OBS.tracer is not None:
-            return self._run_traced(
-                q, qmask, kind=kind, query_batch=query_batch, bucket=bucket,
-            )
+    def _dispatch_modes(self, q, qmask, *, kind: str, bucket=None) -> TopKResult:
         fn = self._active_fn(kind, bucket)
-        if _OBS.metrics is not None:
+        reg = _OBS.metrics
+        with obs.span(
+            "retrieve", kind=kind, layout=self.config.layout,
+            n_shards=self.n_shards,
+        ):
+            if reg is None:
+                return fn(self._index, q, qmask)
             t0 = time.perf_counter()
             out = fn(self._index, q, qmask)
             jax.block_until_ready(out)
-            self._obs_retrieve(_OBS.metrics, kind, time.perf_counter() - t0)
+            self._obs_retrieve(reg, kind, time.perf_counter() - t0)
             return out
-        return fn(self._index, q, qmask)
 
     @staticmethod
     def _obs_retrieve(reg, kind: str, dt: float) -> None:
@@ -346,91 +309,6 @@ class SearchPlan:
             "warp_retrieve_seconds",
             "End-to-end retrieve latency at the plan boundary", kind=kind,
         ).observe(dt)
-
-    def _run_traced(
-        self, q, qmask, *, kind: str, query_batch: bool, bucket=None
-    ) -> TopKResult:
-        """Per-stage spans: warp_select -> bucket_pick -> gather_score ->
-        reduce, with a ``block_until_ready`` fence after each stage so
-        span durations attribute to their stage (the traced path trades
-        async overlap for attribution). Sharded/segmented plans — no
-        ``_staged`` recipe — run their compiled callable under a single
-        ``engine`` span. Stage composition equals the untraced dispatch
-        exactly (``score_from_probes`` -> ``reduce_from_scored`` ==
-        ``finish_from_probes``), so traced results are bit-identical.
-        """
-        tr, reg = _OBS.tracer, _OBS.metrics
-        stg = self._staged
-        t0 = time.perf_counter()
-        with tr.span(
-            "retrieve", kind=kind, layout=self.config.layout,
-            n_shards=self.n_shards, staged=stg is not None,
-        ) as root:
-            if stg is None:
-                with tr.span("engine"):
-                    out = self._active_fn(kind, bucket)(self._index, q, qmask)
-                    jax.block_until_ready(out)
-            else:
-                cfg = stg.base_cfg
-                with tr.span(
-                    "warp_select", nprobe=cfg.nprobe, t_prime=cfg.t_prime,
-                    k_impute=cfg.k_impute,
-                ) as sp:
-                    sel = engine.select_probes(
-                        self._index, q, qmask, cfg, query_batch
-                    )
-                    jax.block_until_ready(sel)
-                self._obs_stage(reg, "warp_select", sp)
-                if bucket is None and stg.pick is not None:
-                    with tr.span("bucket_pick") as sp:
-                        bucket = stg.pick(sel, qmask)
-                        sp.set(bucket=bucket)
-                    root.set(bucket=bucket)
-                run_cfg = stg.cfg_at(bucket)
-                if self._fallback.get("active"):
-                    run_cfg = dataclasses.replace(
-                        run_cfg, executor="reference"
-                    )
-                with tr.span(
-                    "gather_score", gather=run_cfg.gather,
-                    executor=run_cfg.executor, tile_c=run_cfg.tile_c,
-                    buffering=run_cfg.buffering,
-                    worklist_tiles=run_cfg.worklist_tiles,
-                ) as sp:
-                    scored = engine.score_from_probes(
-                        self._index, q, qmask, sel, run_cfg, query_batch,
-                        dfilter=stg.fview,
-                    )
-                    jax.block_until_ready(scored)
-                    if _OBS.kernel_probes:
-                        sp.set(**engine.kernel_dma_compute_split(
-                            self._index, q, qmask, sel, run_cfg
-                        ))
-                self._obs_stage(reg, "gather_score", sp)
-                with tr.span(
-                    "reduce", sort_n=int(scored[0].shape[-1]),
-                    k=run_cfg.k, impl=run_cfg.reduce_impl,
-                ) as sp:
-                    out = engine.reduce_from_scored(
-                        self._index, scored, sel.mse, run_cfg, query_batch,
-                        dfilter=stg.fview,
-                    )
-                    jax.block_until_ready(out)
-                self._obs_stage(reg, "reduce", sp)
-        if reg is not None:
-            self._obs_retrieve(reg, kind, time.perf_counter() - t0)
-        return out
-
-    @staticmethod
-    def _obs_stage(reg, stage: str, sp) -> None:
-        # Stage histograms record only under tracing (the fences that
-        # make a per-stage duration meaningful), on the tracer's clock.
-        if reg is not None and sp.dur is not None:
-            reg.histogram(
-                "warp_stage_seconds",
-                "Per-stage engine latency (traced retrieves only)",
-                stage=stage,
-            ).observe(sp.dur)
 
     def retrieve_batch_at(
         self, q: jax.Array, qmask: jax.Array | None = None, *, bucket: int
@@ -460,8 +338,7 @@ class SearchPlan:
         if qmask is None:
             qmask = jnp.ones(q.shape[:2], bool)
         return self._dispatch(
-            q, jnp.asarray(qmask, bool),
-            kind="batch_at", query_batch=True, bucket=bucket,
+            q, jnp.asarray(qmask, bool), kind="batch_at", bucket=bucket
         )
 
     def adaptive_bucket(self, q: jax.Array, qmask: jax.Array | None = None) -> int | None:
@@ -744,7 +621,6 @@ class Retriever:
             _index=self.index,
             _bucket_for=bucket_for,
             _batch_at=batch_at,
-            _staged=self._staged_recipe(resolved, fctx),
             _fallback_factory=fallback_factory,
             filter_info=(
                 dfilter.describe() if dfilter is not None else None
@@ -943,35 +819,10 @@ class Retriever:
             and len(cfg.worklist_buckets) > 1
         )
 
-    def _staged_recipe(self, cfg: WarpSearchConfig, fctx=None):
-        """The ``_StagedLocal`` recipe the traced path re-composes the
-        pipeline from, or None on sharded/segmented indexes (their stages
-        run inside ``shard_map`` / per-segment merges — one engine span)."""
-        if self.is_sharded or self.is_segmented:
-            return None
-        if self._is_adaptive(cfg):
-            pick = self._local_sel_picker(cfg, fview=fctx)
-
-            def cfg_at(b, _cfg=cfg):
-                if b is None:
-                    return _cfg
-                return dataclasses.replace(
-                    _cfg, worklist_tiles=b, worklist_buckets=None
-                )
-
-        else:
-            pick = None
-
-            def cfg_at(b, _cfg=cfg):
-                return _cfg
-
-        return _StagedLocal(cfg, pick, cfg_at, fview=fctx)
-
     def _local_sel_picker(self, cfg: WarpSearchConfig, fview=None):
         """``(sel, qmask) -> smallest ladder rung`` fitting the masked
-        probe tile demand of a WARP_SELECT output — shared by the
-        adaptive dispatcher and the traced staged path so the two rung
-        choices cannot drift. With ``fview`` probe runs whose cluster
+        probe tile demand of a WARP_SELECT output — the adaptive
+        dispatcher's rung choice. With ``fview`` probe runs whose cluster
         holds no surviving tokens count zero tiles (the worklist drops
         them), so a selective filter lowers the chosen rung."""
         buckets = cfg.worklist_buckets
@@ -1199,9 +1050,7 @@ class Retriever:
 
         # Local path: stage 1 runs ONCE (select_probes), the bucket is
         # read off its probe sizes, and stages 2+3 finish under the
-        # bucket's static bound — no duplicated work at all. The picker is
-        # shared with the traced staged path (``_local_sel_picker``) so
-        # traced and untraced rung choices cannot drift.
+        # bucket's static bound — no duplicated work at all.
         bucket_from_sel = self._local_sel_picker(cfg, fview=fctx)
 
         def bucket_for(q, qmask):

@@ -28,7 +28,11 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-__all__ = ["WarpSelectOut", "warp_select", "impute_mse"]
+__all__ = ["WarpSelectOut", "warp_select", "impute_mse", "SCOPE_SELECT"]
+
+# Named scope of stage 1: every op ``warp_select`` emits carries it in its
+# HLO ``op_name`` (``core/engine.py`` names the other two stages).
+SCOPE_SELECT = "warp.select"
 
 
 class WarpSelectOut(NamedTuple):
@@ -84,25 +88,26 @@ def warp_select(
     qmask (optional bool[Q]): masked query tokens get m_i = 0 and their
     probe entries are still emitted (the engine drops their candidates).
     """
-    kk = max(nprobe, k_impute)
-    s_cq = q @ centroids.T  # [Q, C]
-    # The barrier keeps XLA from re-deriving the [:nprobe] slices below as
-    # a second top-k over all C centroids, which the TPU compiler lowers
-    # far more slowly (~15 s per program at 2^17 centroids); the values
-    # are the same either way.
-    top_scores, top_cids = jax.lax.top_k(s_cq, kk)  # [Q, kk] desc
-    top_scores = jax.lax.optimization_barrier(top_scores)
-    top_cids = jax.lax.optimization_barrier(top_cids)
-    top_sizes = cluster_sizes[top_cids]  # [Q, kk]
-    mse = impute_mse(top_scores, top_sizes, t_prime, qmask)
-    return WarpSelectOut(
-        probe_scores=top_scores[:, :nprobe],
-        probe_cids=top_cids[:, :nprobe].astype(jnp.int32),
-        # Probe metadata for downstream worklist construction: the ragged
-        # layout builds tile counts from the true cluster sizes, already in
-        # hand here — re-emitting them saves a second gather in the engine.
-        probe_sizes=top_sizes[:, :nprobe].astype(jnp.int32),
-        mse=mse,
-        top_scores=top_scores,
-        top_sizes=top_sizes.astype(jnp.int32),
-    )
+    with jax.named_scope(SCOPE_SELECT):
+        kk = max(nprobe, k_impute)
+        s_cq = q @ centroids.T  # [Q, C]
+        # The barrier keeps XLA from re-deriving the [:nprobe] slices below as
+        # a second top-k over all C centroids, which the TPU compiler lowers
+        # far more slowly (~15 s per program at 2^17 centroids); the values
+        # are the same either way.
+        top_scores, top_cids = jax.lax.top_k(s_cq, kk)  # [Q, kk] desc
+        top_scores = jax.lax.optimization_barrier(top_scores)
+        top_cids = jax.lax.optimization_barrier(top_cids)
+        top_sizes = cluster_sizes[top_cids]  # [Q, kk]
+        mse = impute_mse(top_scores, top_sizes, t_prime, qmask)
+        return WarpSelectOut(
+            probe_scores=top_scores[:, :nprobe],
+            probe_cids=top_cids[:, :nprobe].astype(jnp.int32),
+            # Probe metadata for downstream worklist construction: the ragged
+            # layout builds tile counts from the true cluster sizes, already in
+            # hand here — re-emitting them saves a second gather in the engine.
+            probe_sizes=top_sizes[:, :nprobe].astype(jnp.int32),
+            mse=mse,
+            top_scores=top_scores,
+            top_sizes=top_sizes.astype(jnp.int32),
+        )

@@ -175,5 +175,6 @@ def selective_sum_kernel_call(
             (_round_up(rows, OUT_ROWS), tile_n), jnp.float32
         ),
         interpret=interpret,
+        name="warp_decompress_score",
     )(packed, w)
     return out[:rows].reshape(q, n)

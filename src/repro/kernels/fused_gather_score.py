@@ -486,6 +486,7 @@ def fused_gather_score_kernel_call(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((_out_rows(steps), tile_c), jnp.float32),
         interpret=interpret,
+        name="warp_fused_gather_score_dense",
     )(starts, sizes, probe_scores.astype(jnp.float32), codes, w)
     return out[:steps].reshape(qm, p, cap_pad)
 
@@ -669,6 +670,7 @@ def ragged_fused_gather_score_kernel_call(
             ),
             out_shape=jax.ShapeDtypeStruct((_out_rows(w), tile_c), jnp.float32),
             interpret=interpret,
+            name="warp_fused_gather_score_ragged",
         )(*chunk, codes, wt)
         outs.append(out[:w].reshape(-1))
     return jnp.concatenate(outs) if len(outs) > 1 else outs[0]

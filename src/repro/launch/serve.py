@@ -17,9 +17,10 @@ with Zipf-skewed query popularity (``--zipf-skew``) for ``--duration-s``
 seconds — the traffic shape that exercises the bucket-aware scheduler's
 per-rung batching and the result cache.
 
-Observability (``repro.obs``): ``--trace-out trace.json`` records one
-span tree per request — admission, rung pre-pass, queue wait, batch
-dispatch, engine stages, reply — as Chrome trace-event JSON for
+Observability (``repro.obs``): ``--trace-out trace.json`` records the
+``serve.*`` spans of each request — submit (admission, rung pre-pass),
+queue wait, and the step that served it (assemble, dispatch with its
+``retrieve``, await, reply) — as Chrome trace-event JSON for
 https://ui.perfetto.dev; ``--metrics-dump metrics.prom`` (or ``.json``)
 writes the serving/engine metric registry at exit;
 ``--metrics-interval-s`` flushes a one-line summary periodically during

@@ -16,20 +16,25 @@ Runtime state is a tri-level switch held in ``STATE``:
 
   disabled (default)   instrumented hot paths pay one attribute check
                        (``STATE.tracer is None`` / ``STATE.metrics is
-                       None``) — measured < 2% on the retrieve path
-                       (``benchmarks/bench_obs.py`` -> BENCH_obs.json).
+                       None``) plus, per span, one check whether a
+                       profiler session records — measured < 2% on the
+                       retrieve path (``benchmarks/bench_obs.py`` ->
+                       BENCH_obs.json).
   metrics              ``enable_metrics()``: counters/histograms record;
                        no spans, no forced synchronization beyond the
                        retrieve-latency block.
-  tracing              ``set_tracer(Tracer(...))``: per-stage spans with
-                       ``jax.block_until_ready`` fences between engine
-                       stages (observer effect by design — a span's dur
-                       must mean "this stage", so the traced path trades
-                       async dispatch overlap for attribution).
+  tracing              ``set_tracer(Tracer(...))``: spans into the
+                       tracer's ring buffer. The program is the same one
+                       that runs untraced (no fences, no other compiled
+                       callable); engine stage times come from the
+                       device trace, where each op carries its stage's
+                       ``warp.*`` named scope.
 
-``set_kernel_probes(True)`` additionally re-times the fused gather-score
-kernel with the PR 6 ``probe`` carve-outs (dma-only / compute-only) on
-every traced retrieve — expensive, profiling sessions only.
+``span`` is the one call site for both sinks: while a profiler session
+records (``jax.profiler.start_trace``), every span also opens a
+``jax.profiler.TraceAnnotation`` of the same name and arguments, so the
+server's spans sit on the profiler's clock in the same trace as the
+device ops, with or without a tracer installed.
 
 Layering: ``repro.obs`` imports nothing from the rest of ``repro`` —
 core, serving, store, and launch all import *it*. Instrument sparse call
@@ -39,6 +44,8 @@ metric object references directly.
 """
 
 from __future__ import annotations
+
+from jax.profiler import TraceAnnotation
 
 from repro.obs.metrics import (
     DEFAULT_LATENCY_BUCKETS_S,
@@ -55,6 +62,7 @@ from repro.obs.trace import (
     NULL_SPAN,
     NULL_TRACER,
     NullTracer,
+    ProfiledSpan,
     Span,
     Tracer,
     span_tree,
@@ -66,10 +74,10 @@ __all__ = [
     "DEFAULT_LATENCY_BUCKETS_S", "Stopwatch", "percentiles", "time_fn",
     # tracing
     "Span", "Tracer", "NullTracer", "NULL_TRACER", "NULL_SPAN",
-    "span_tree",
+    "ProfiledSpan", "span_tree",
     # runtime state
     "STATE", "enable_metrics", "disable_metrics", "set_tracer", "tracer",
-    "set_kernel_probes", "disable_all",
+    "disable_all",
     # convenience instrumentation
     "count", "gauge", "observe", "span",
 ]
@@ -78,12 +86,11 @@ __all__ = [
 class _ObsState:
     """Process-wide observability switch (see module docstring)."""
 
-    __slots__ = ("metrics", "tracer", "kernel_probes")
+    __slots__ = ("metrics", "tracer")
 
     def __init__(self):
         self.metrics: MetricsRegistry | None = None
         self.tracer: Tracer | None = None
-        self.kernel_probes: bool = False
 
 
 STATE = _ObsState()
@@ -113,18 +120,10 @@ def tracer():
     return t if t is not None else NULL_TRACER
 
 
-def set_kernel_probes(on: bool) -> None:
-    """Arm the DMA/compute kernel carve-out timing on traced retrieves
-    (``core/engine.py::kernel_dma_compute_split``). Expensive — each
-    traced retrieve re-runs the gather-score kernel several times."""
-    STATE.kernel_probes = bool(on)
-
-
 def disable_all() -> None:
     """Back to the zero-overhead default (tests reset through this)."""
     STATE.metrics = None
     STATE.tracer = None
-    STATE.kernel_probes = False
 
 
 # ---- sparse-call-site one-liners (no-ops when disabled) ----
@@ -152,7 +151,11 @@ def observe(
 
 
 def span(name: str, **args):
-    """Context-manager span against the active tracer (``NULL_SPAN``
-    when tracing is off)."""
+    """Context-manager span: into the active tracer, and as a profiler
+    annotation of the same name while a profiler session records
+    (``NULL_SPAN`` when neither is on)."""
     t = STATE.tracer
-    return t.span(name, **args) if t is not None else NULL_SPAN
+    inner = t.span(name, **args) if t is not None else NULL_SPAN
+    if TraceAnnotation.is_enabled():
+        return ProfiledSpan(name, args, inner)
+    return inner

@@ -29,6 +29,12 @@ nesting by interval containment for tests and programmatic analysis.
 The disabled path is ``NULL_TRACER``/``NULL_SPAN``: shared singletons
 whose ``span()`` allocates nothing — instrumented call sites pay one
 attribute check when tracing is off (see ``repro.obs.STATE``).
+
+``ProfiledSpan`` is the second sink: it wraps a span (live or null) in a
+``jax.profiler.TraceAnnotation`` of the same name, so while a profiler
+session records, the span lands in its ``.xplane.pb`` on the profiler's
+clock, beside the device ops. ``repro.obs.span`` builds one only while a
+session records.
 """
 
 from __future__ import annotations
@@ -40,7 +46,10 @@ import time
 from collections import deque
 from typing import Callable
 
+from jax.profiler import TraceAnnotation
+
 __all__ = [
+    "ProfiledSpan",
     "Span",
     "Tracer",
     "NullTracer",
@@ -139,6 +148,44 @@ class _NullSpan:
 
 
 NULL_SPAN = _NullSpan()
+
+
+def _annotation_args(args: dict) -> dict:
+    # The profiler stores annotation arguments as ``k=v`` pairs split at
+    # commas, so a list (``rids``) goes in as one space-separated string.
+    return {
+        k: " ".join(map(str, v)) if isinstance(v, (list, tuple)) else v
+        for k, v in args.items()
+        if v is not None
+    }
+
+
+class ProfiledSpan:
+    """A span that is also a ``jax.profiler.TraceAnnotation`` of the same
+    name and arguments: ``inner`` (a tracer's live span or ``NULL_SPAN``)
+    records into the ring buffer, the annotation into the profiler's
+    trace. ``set`` reaches both."""
+
+    __slots__ = ("_inner", "_note")
+
+    def __init__(self, name: str, args: dict, inner):
+        self._inner = inner
+        self._note = TraceAnnotation(name, **_annotation_args(args))
+
+    def set(self, **kw) -> "ProfiledSpan":
+        self._inner.set(**kw)
+        self._note.set_metadata(**_annotation_args(kw))
+        return self
+
+    def __enter__(self) -> "ProfiledSpan":
+        self._note.__enter__()
+        self._inner.__enter__()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self._inner.__exit__(*exc)
+        self._note.__exit__(*exc)
+        return False
 
 
 class NullTracer:

@@ -315,6 +315,10 @@ class RetrievalServer:
                 "Failed maintain() ticks rolled back and scheduled for retry",
             ),
         }
+        self._c_step_host = self.metrics.counter(
+            "serving_step_host_seconds_total",
+            "Host time inside step() outside serve.await, on the server clock",
+        )
         self._g_health = self.metrics.gauge(
             "serving_health_status",
             "health() status: 0=ok, 1=degraded, 2=overloaded",
@@ -338,9 +342,18 @@ class RetrievalServer:
 
     @property
     def stats(self) -> dict:
-        """Legacy counter dict (batches/padded_slots/served/reloads/
-        cache_hits/compactions), reconstructed from the registry."""
-        return {k: int(c.value) for k, c in self._c.items()}
+        """Integer counters reconstructed from the registry: the legacy
+        dict (batches/padded_slots/served/reloads/cache_hits/compactions/
+        deadline_shed/maintain_retries) plus two times in microseconds on
+        the server clock: ``queue_wait_us``, submit-to-dispatch wait
+        summed over dispatched requests (the scheduler's
+        ``serving_queue_wait_seconds``), and ``step_host_us``, host time
+        inside ``step`` outside the wait for the device
+        (``serving_step_host_seconds_total``)."""
+        out = {k: int(c.value) for k, c in self._c.items()}
+        out["queue_wait_us"] = round(self.scheduler.queue_wait_seconds() * 1e6)
+        out["step_host_us"] = round(self._c_step_host.value * 1e6)
+        return out
 
     # ---- multi-tenant routing ----
     def _state(self, tenant) -> _Tenant:
@@ -524,7 +537,7 @@ class RetrievalServer:
             self._rung_cache.purge_epochs_below(self.index_epoch)
         self._rehome()
         obs.tracer().instant(
-            "delete_documents",
+            "serve.delete_documents",
             tenant="default" if tenant is None else tenant,
             tombstones=len(st.deleted),
         )
@@ -610,9 +623,11 @@ class RetrievalServer:
         """
         if qmask is None:
             qmask = np.ones(q.shape[:-1], bool)
-        with obs.span("submit", queue_depth=len(self.scheduler)) as sp:
+        # Spans carry the request id; the gate runs before the id is
+        # taken, so its span names the id the request gets if admitted.
+        with obs.span("serve.submit", queue_depth=len(self.scheduler)) as sp:
             if self.admission is not None:
-                with obs.span("admission"):
+                with obs.span("serve.admission", rid=self._next_id):
                     self.admission.check(len(self.scheduler))
             # Resolve routing before burning an id: unknown tenant /
             # mis-sized filter raises with nothing enqueued.
@@ -638,7 +653,7 @@ class RetrievalServer:
                     tc["served"].inc()
                     sp.set(cache_hit=True)
                     return rid
-            with obs.span("rung_prepass") as rp:
+            with obs.span("serve.rung_prepass", rid=rid) as rp:
                 rung = self._rung_for(q, qmask, qkey, plan=plan, fp=fp)
                 rp.set(rung=rung)
             now = self.clock()
@@ -867,7 +882,7 @@ class RetrievalServer:
         self.metrics.histogram(
             "serving_reload_seconds", "Hot index swap duration"
         ).observe(time.perf_counter() - t0)
-        obs.tracer().instant("reload", epoch=self.index_epoch)
+        obs.tracer().instant("serve.reload", epoch=self.index_epoch)
 
     def maintain(self) -> bool:
         """One background-maintenance tick: compact + reload when the
@@ -897,7 +912,7 @@ class RetrievalServer:
         try:
             if not self.compaction.should_compact(delta_stats(self.store_path)):
                 return False
-            with obs.span("compaction", store=self.store_path):
+            with obs.span("serve.compaction", store=self.store_path):
                 compact(self.store_path)
                 self._last_compact = self.clock()
                 self.reload(self.store_path)
@@ -955,56 +970,75 @@ class RetrievalServer:
         return len(expired)
 
     def step(self, *, force: bool = False) -> int:
-        """Dispatch at most one batch; returns number of requests served."""
-        self._reap_expired()
-        got = self.scheduler.next_batch(force=force)
-        if got is None:
-            return 0
-        rung, batch = got
-        tr = obs.STATE.tracer
-        if tr is not None:
-            # Retroactive queue-wait rows: the wait is measured on the
-            # server clock (same clock as ``arrival``) but anchored so
-            # the interval *ends now* on the tracer's clock — the two
-            # clocks may have different epochs. ``tid=request id`` gives
-            # each request its own Perfetto row.
-            now_srv, now_tr = self.clock(), tr.clock()
-            for p in batch:
-                wait = max(now_srv - p.arrival, 0.0)
-                tr.add_event(
-                    "queue_wait", now_tr - wait, wait, tid=p.req_id,
-                    rung="none" if rung is None else rung,
-                )
-        t0 = time.perf_counter()
-        # Every member shares the batch group (tenant + filter), so the
-        # head's resolved plan serves the whole batch; legacy pendings
-        # (pre-multi-tenant pickles/tests) fall back to the default plan.
-        plan = batch[0].plan if batch[0].plan is not None else self.plan
-        tenant = batch[0].tenant
-        with obs.span(
-            "batch_dispatch",
-            rung="none" if rung is None else rung,
-            tenant="default" if tenant is None else tenant,
-            batch_size=len(batch), rids=[p.req_id for p in batch],
-        ):
-            b = self.policy.max_batch
-            qm, d = batch[0].q.shape
-            q = np.zeros((b, qm, d), np.float32)
-            mask = np.zeros((b, qm), bool)
-            for i, p in enumerate(batch):
-                q[i] = p.q
-                mask[i] = p.qmask
-            qd, md = jnp.asarray(q), jnp.asarray(mask)
-            if rung is None:
-                res = plan.retrieve_batch(qd, md)
-            else:
-                # The batch executes at its rung — every member (and each
-                # backfilled lower-rung rider) fits it, and padding rows
-                # are fully masked so they add no worklist demand.
-                res = plan.retrieve_batch_at(qd, md, bucket=rung)
-            with obs.span("reply"):
+        """Dispatch at most one batch; returns number of requests served.
+
+        Spans (``obs.span``; each carries the batch's request ids):
+        ``serve.step`` around the whole call (an empty step too), and
+        inside it ``serve.assemble`` (pack the queries, move them to the
+        device), ``serve.dispatch`` (the plan call, which only enqueues),
+        ``serve.await`` (the device-to-host copy of the result, which
+        blocks until the device is done) and ``serve.reply`` (fan-out and
+        cache fills). Host time inside ``step`` outside ``serve.await``
+        accrues to ``serving_step_host_seconds_total``."""
+        t_enter = self.clock()
+        with obs.span("serve.step") as step_span:
+            self._reap_expired()
+            got = self.scheduler.next_batch(force=force)
+            if got is None:
+                self._c_step_host.inc(self.clock() - t_enter)
+                return 0
+            rung, batch = got
+            rids = [p.req_id for p in batch]
+            # Every member shares the batch group (tenant + filter), so
+            # the head's resolved plan serves the whole batch; legacy
+            # pendings (pre-multi-tenant pickles/tests) fall back to the
+            # default plan.
+            plan = batch[0].plan if batch[0].plan is not None else self.plan
+            tenant = batch[0].tenant
+            step_span.set(
+                rung="none" if rung is None else rung,
+                tenant="default" if tenant is None else tenant,
+                batch_size=len(batch), rids=rids,
+            )
+            tr = obs.STATE.tracer
+            if tr is not None:
+                # Retroactive queue-wait rows: the wait is measured on the
+                # server clock (same clock as ``arrival``) but anchored so
+                # the interval *ends now* on the tracer's clock — the two
+                # clocks may have different epochs. ``tid=request id``
+                # gives each request its own Perfetto row.
+                now_srv, now_tr = self.clock(), tr.clock()
+                for p in batch:
+                    wait = max(now_srv - p.arrival, 0.0)
+                    tr.add_event(
+                        "serve.queue_wait", now_tr - wait, wait,
+                        tid=p.req_id, rung="none" if rung is None else rung,
+                    )
+            t0 = time.perf_counter()
+            with obs.span("serve.assemble", rids=rids):
+                b = self.policy.max_batch
+                qm, d = batch[0].q.shape
+                q = np.zeros((b, qm, d), np.float32)
+                mask = np.zeros((b, qm), bool)
+                for i, p in enumerate(batch):
+                    q[i] = p.q
+                    mask[i] = p.qmask
+                qd, md = jnp.asarray(q), jnp.asarray(mask)
+            with obs.span("serve.dispatch", rids=rids):
+                if rung is None:
+                    res = plan.retrieve_batch(qd, md)
+                else:
+                    # The batch executes at its rung — every member (and
+                    # each backfilled lower-rung rider) fits it, and
+                    # padding rows are fully masked so they add no
+                    # worklist demand.
+                    res = plan.retrieve_batch_at(qd, md, bucket=rung)
+            with obs.span("serve.await", rids=rids):
+                t_wait = self.clock()
                 scores = np.asarray(res.scores)
                 docs = np.asarray(res.doc_ids)
+                t_wait = self.clock() - t_wait
+            with obs.span("serve.reply", rids=rids):
                 tc = self._tenant_counters(tenant)
                 for i, p in enumerate(batch):
                     pair = (scores[i], docs[i])
@@ -1015,10 +1049,11 @@ class RetrievalServer:
                         self.result_cache.put(
                             self._cache_key(p.qkey, p.fp), pair
                         )
-        self._h_dispatch.observe(time.perf_counter() - t0)
-        self._c["batches"].inc()
-        self._c["padded_slots"].inc(b - len(batch))
-        self._c["served"].inc(len(batch))
+            self._h_dispatch.observe(time.perf_counter() - t0)
+            self._c["batches"].inc()
+            self._c["padded_slots"].inc(b - len(batch))
+            self._c["served"].inc(len(batch))
+            self._c_step_host.inc(self.clock() - t_enter - t_wait)
         return len(batch)
 
     def drain(self) -> None:

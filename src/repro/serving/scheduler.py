@@ -154,6 +154,15 @@ class BucketScheduler:
             },
         }
 
+    def queue_wait_seconds(self) -> float:
+        """Admission-to-dispatch wait summed over every dispatched
+        request: the sum of the ``serving_queue_wait_seconds`` series of
+        every rung in the registry (they outlive a scheduler rebuilt on
+        ``reload``)."""
+        return sum(
+            h.sum for h in self.metrics.series("serving_queue_wait_seconds")
+        )
+
     # ---- queue state ----
     def __len__(self) -> int:
         return sum(len(q) for q in self._queues.values())

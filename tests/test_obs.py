@@ -1,17 +1,28 @@
 """Observability substrate (``repro.obs``): deterministic metrics +
-tracing, exposition goldens, and the load-bearing claim that the traced
-stage-split retrieve path is BIT-IDENTICAL to the untraced dispatch
-(``score_from_probes`` -> ``reduce_from_scored`` composes exactly like
-``finish_from_probes``)."""
+tracing, exposition goldens, and the load-bearing claims that tracing
+changes nothing about the retrieve program (the same compiled callable,
+no fence, no compilation, bit-identical results) while every op of that
+program carries its stage's ``warp.*`` named scope for the device trace,
+and that server spans also reach the profiler's trace."""
 
+import dataclasses
+import glob
 import json
+import re
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro import obs
-from repro.core import IndexBuildConfig, Retriever, WarpSearchConfig, build_index
+from repro.core import (
+    IndexBuildConfig,
+    Retriever,
+    WarpSearchConfig,
+    build_index,
+    engine,
+)
 from repro.data import make_corpus, make_queries
 from repro.obs import (
     Histogram,
@@ -286,6 +297,9 @@ def test_traced_retrieve_bit_identical(setup, cfg):
 
 
 def test_traced_spans_cover_stages(setup):
+    """A traced retrieve is one ``retrieve`` span around the compiled
+    callable; the stages are scopes inside the program, not spans
+    (``test_compiled_ops_carry_one_stage_scope``)."""
     _, idx, q, qmask, _ = setup
     plan = Retriever.from_index(idx).plan(RAGGED)
     plan.retrieve(q[0], qmask[0])  # compile untraced first
@@ -293,15 +307,9 @@ def test_traced_spans_cover_stages(setup):
     plan.retrieve(q[0], qmask[0])
     tree = span_tree(tr.events())
     assert [n["span"].name for n in tree] == ["retrieve"]
-    kids = [c["span"].name for c in tree[0]["children"]]
-    assert kids == ["warp_select", "bucket_pick", "gather_score", "reduce"]
+    assert tree[0]["children"] == []
     root = tree[0]["span"]
-    assert root.args["layout"] == "ragged" and root.args["staged"] is True
-    assert root.args["bucket"] in plan.config.worklist_buckets
-    # Stage durations nest inside the root span.
-    for c in tree[0]["children"]:
-        assert c["span"].ts >= root.ts
-        assert c["span"].end <= root.end + 1e-9
+    assert root.args == {"kind": "single", "layout": "ragged", "n_shards": 1}
 
 
 def test_traced_batch_at_parity(setup):
@@ -314,10 +322,13 @@ def test_traced_batch_at_parity(setup):
     np.testing.assert_array_equal(
         np.asarray(base.doc_ids), np.asarray(traced.doc_ids)
     )
-    # Forced rung: no bucket_pick span, the rung came from the caller.
-    names = [s.name for s in tr.events()]
-    assert "bucket_pick" not in names
-    assert {"warp_select", "gather_score", "reduce"} <= set(names)
+    np.testing.assert_array_equal(
+        np.asarray(base.scores), np.asarray(traced.scores)
+    )
+    # Forced rung: one retrieve span of that kind, nothing inside it.
+    assert [(s.name, s.args["kind"]) for s in tr.events()] == [
+        ("retrieve", "batch_at")
+    ]
 
 
 def test_metrics_only_counts_retrieves(setup):
@@ -331,14 +342,135 @@ def test_metrics_only_counts_retrieves(setup):
     assert reg.counter("warp_retrieves_total", kind="batch").value == 1
     h = reg.histogram("warp_retrieve_seconds", kind="single")
     assert h.count == 3 and h.sum > 0
-    # No stage histograms without tracing (no fences -> not meaningful).
-    assert reg.series("warp_stage_seconds") == []
+    # With a tracer too, a retrieve still counts once, and nothing
+    # records per-stage host times.
     obs.set_tracer(Tracer())
     plan.retrieve(q[0], qmask[0])
-    stages = {
-        dict(m.labels)["stage"] for m in reg.series("warp_stage_seconds")
+    assert reg.counter("warp_retrieves_total", kind="single").value == 4
+    assert all(m.name.startswith("warp_retrieve") for m in reg.metrics())
+
+
+_STAGE_OPS = ("sort", "gather", "scatter", "dot", "custom-call")
+_INSTR = re.compile(r"^\s*(?:ROOT )?%?[\w.\-]+ = .*? ([\w\-]+)\(")
+
+
+def _unscoped_stage_ops(hlo: str) -> tuple[list, set]:
+    """(instructions of the stage opcodes without exactly one ``warp.*``
+    scope, every scope seen) in an optimized HLO module's text. Under
+    ``vmap`` a scope reads ``vmap(warp.reduce)/...``."""
+    bad, seen = [], set()
+    for line in hlo.splitlines():
+        m = _INSTR.match(line)
+        if m is None or m.group(1) not in _STAGE_OPS:
+            continue
+        name = re.search(r'op_name="([^"]*)"', line)
+        scopes = set(re.findall(
+            r"warp\.(?:select|gather_score|reduce)(?=[/)])",
+            name.group(1) if name else "",
+        ))
+        seen |= scopes
+        if len(scopes) != 1:
+            bad.append(line.strip())
+    return bad, seen
+
+
+@pytest.mark.parametrize("cfg", [
+    WarpSearchConfig(nprobe=8, k=5, t_prime=400),  # dense
+    RAGGED,                                        # adaptive ragged
+], ids=["dense", "ragged"])
+def test_compiled_ops_carry_one_stage_scope(setup, cfg):
+    """Every sort, gather, scatter, dot and custom call of the optimized
+    retrieve program names exactly one stage scope — the batch program
+    the serving path runs, and the adaptive plan's select_probes ->
+    finish_from_probes pair."""
+    _, idx, q, qmask, _ = setup
+    plan = Retriever.from_index(idx).plan(cfg)
+    c, ix = plan.config, plan._index
+    qb, mb = jnp.asarray(q[:4], jnp.float32), jnp.asarray(qmask[:4], bool)
+    texts = [engine._search_many.lower(ix, qb, mb, c).compile().as_text()]
+    if c.worklist_buckets:
+        sel = engine.select_probes(ix, qb, mb, c, True)
+        texts.append(
+            engine.select_probes.lower(ix, qb, mb, c, True).compile().as_text()
+        )
+        fcfg = dataclasses.replace(
+            c, worklist_tiles=c.worklist_buckets[-1], worklist_buckets=None
+        )
+        texts.append(
+            engine.finish_from_probes.lower(ix, qb, mb, sel, fcfg, True)
+            .compile().as_text()
+        )
+    seen = set()
+    for text in texts:
+        bad, got = _unscoped_stage_ops(text)
+        assert bad == [], bad[:5]
+        seen |= got
+    assert seen == set(engine.STAGE_SCOPES)
+
+
+@pytest.mark.parametrize("cfg", [
+    WarpSearchConfig(nprobe=8, k=5, t_prime=400),  # dense
+    RAGGED,                                        # adaptive ragged
+], ids=["dense", "ragged"])
+def test_tracer_after_warmup_compiles_nothing(setup, cfg):
+    """Installing a tracer after warm-up runs the same compiled programs:
+    no compilation event, and bit-identical results."""
+    _, idx, q, qmask, _ = setup
+    plan = Retriever.from_index(idx).plan(cfg)
+    base = plan.retrieve_batch(q[:4], qmask[:4])
+    compiles = []
+
+    def listen(event, duration, **kw):
+        if event.startswith("/jax/core/compile/"):
+            compiles.append(event)
+
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    try:
+        obs.set_tracer(Tracer())
+        traced = plan.retrieve_batch(q[:4], qmask[:4])
+        jax.block_until_ready(traced)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listen)
+    assert compiles == []
+    np.testing.assert_array_equal(
+        np.asarray(base.doc_ids), np.asarray(traced.doc_ids)
+    )
+    np.testing.assert_array_equal(
+        np.asarray(base.scores), np.asarray(traced.scores)
+    )
+
+
+def test_spans_reach_the_profiler_trace(tmp_path):
+    """While a profiler session records, ``obs.span`` also writes a
+    ``TraceAnnotation`` of the same name, with or without a tracer; a
+    list argument arrives as one space-separated stat."""
+    from jax.profiler import ProfileData
+
+    assert obs.span("serve.step") is obs.NULL_SPAN  # no session, no tracer
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with obs.span("serve.step", rids=[3, 4]):
+            with obs.span("serve.await", rids=[3, 4]) as sp:
+                sp.set(rung=8)
+        tr = obs.set_tracer(Tracer())
+        with obs.span("retrieve", kind="batch"):
+            pass
+    finally:
+        jax.profiler.stop_trace()
+    assert [s.name for s in tr.events()] == ["retrieve"]
+    path = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)[0]
+    got = {
+        ev.name: dict(ev.stats)
+        for plane in ProfileData.from_file(path).planes
+        for line in plane.lines
+        for ev in line.events
+        if ev.name in ("serve.step", "serve.await", "retrieve")
     }
-    assert {"warp_select", "gather_score", "reduce"} <= stages
+    assert got == {
+        "serve.step": {"rids": "3 4"},
+        "serve.await": {"rids": "3 4", "rung": 8},
+        "retrieve": {"kind": "batch"},
+    }
 
 
 def test_disabled_dispatch_overhead_smoke(setup):
@@ -365,9 +497,10 @@ def test_disabled_dispatch_overhead_smoke(setup):
 
 
 def test_serving_end_to_end_trace(setup):
-    """One request's lifecycle shows up as spans: submit (admission +
-    rung pre-pass) -> queue_wait -> batch_dispatch -> engine stages ->
-    reply, with server and tracer sharing one injected clock."""
+    """One request's lifecycle shows up as ``serve.*`` spans: submit
+    (rung pre-pass) -> queue_wait -> step (assemble, dispatch, await,
+    reply), each carrying request ids, with server and tracer sharing one
+    injected clock."""
     _, idx, q, qmask, _ = setup
     clock = _FakeClock()
     server = RetrievalServer(
@@ -380,20 +513,36 @@ def test_serving_end_to_end_trace(setup):
     r1 = server.submit(q[1], qmask[1])
     clock.tick(0.25)
     assert server.step(force=True) == 2
-    names = [s.name for s in tr.events()]
-    for name in ("submit", "rung_prepass", "queue_wait", "batch_dispatch",
-                 "retrieve", "warp_select", "gather_score", "reduce",
-                 "reply"):
+    events = tr.events()
+    names = [s.name for s in events]
+    for name in ("serve.submit", "serve.rung_prepass", "serve.queue_wait",
+                 "serve.step", "serve.assemble", "serve.dispatch",
+                 "retrieve", "serve.await", "serve.reply"):
         assert name in names, (name, names)
-    waits = {s.tid: s for s in tr.events() if s.name == "queue_wait"}
+    assert not [n for n in names if n not in ("retrieve",)
+                and not n.startswith("serve.")]
+    waits = {s.tid: s for s in events if s.name == "serve.queue_wait"}
     assert set(waits) == {r0, r1}
     # Shared clock: the waits are exact and end at the dispatch instant.
     assert waits[r0].dur == pytest.approx(0.75)
     assert waits[r1].dur == pytest.approx(0.25)
     assert waits[r0].end == pytest.approx(0.75)
-    disp = next(s for s in tr.events() if s.name == "batch_dispatch")
-    assert disp.args["batch_size"] == 2
-    assert sorted(disp.args["rids"]) == [r0, r1]
+    subs = [s.args["rid"] for s in events if s.name == "serve.submit"]
+    pre = [s.args["rid"] for s in events if s.name == "serve.rung_prepass"]
+    assert subs == pre == [r0, r1]
+    step = next(s for s in events if s.name == "serve.step")
+    assert step.args["batch_size"] == 2
+    assert sorted(step.args["rids"]) == [r0, r1]
+    # The four phases of the step inside it, in order (spans record as
+    # they close), each with the ids.
+    inner = [s for s in events if s.name != "serve.queue_wait"
+             and s.ts >= step.ts and s.end <= step.end and s is not step]
+    assert [s.name for s in inner if s.name.startswith("serve.")][-4:] == [
+        "serve.assemble", "serve.dispatch", "serve.await", "serve.reply"
+    ]
+    assert all(sorted(s.args["rids"]) == [r0, r1]
+               for s in inner if s.name.startswith("serve."))
+    assert events[-1] is step
     assert server.poll(r0) is not None and server.poll(r1) is not None
 
 
@@ -410,11 +559,13 @@ def test_server_stats_backcompat_and_registry(setup):
     assert st["served"] == 3 and st["batches"] >= 1
     assert set(st) == {"batches", "padded_slots", "served", "reloads",
                        "cache_hits", "compactions", "deadline_shed",
-                       "maintain_retries"}
+                       "maintain_retries", "queue_wait_us", "step_host_us"}
+    assert all(type(v) is int for v in st.values())
     # The same numbers are Prometheus-visible through the registry.
     text = server.metrics.to_prometheus()
     assert "serving_requests_served_total 3" in text
     assert "serving_queue_wait_seconds_count" in text
+    assert "serving_step_host_seconds_total" in text
     snap = server.metrics.snapshot()
     assert snap["serving_batches_total"]["series"][0]["value"] == st["batches"]
     # Private registry per server: a second server starts at zero.
@@ -423,6 +574,54 @@ def test_server_stats_backcompat_and_registry(setup):
         BatchPolicy(max_batch=4, max_wait_s=10.0), _FakeClock(),
     )
     assert other.stats["served"] == 0
+
+
+def test_stats_queue_wait_and_step_host_exact(setup):
+    """``queue_wait_us`` sums submit-to-dispatch waits and
+    ``step_host_us`` the host time of ``step`` outside the device wait,
+    both on the server clock: exact under a fake clock that the plan
+    call (host) and the result copy (device wait) advance."""
+    _, idx, q, qmask, _ = setup
+    clock = _FakeClock()
+    server = RetrievalServer(
+        Retriever.from_index(idx), WarpSearchConfig(nprobe=8, k=5),
+        BatchPolicy(max_batch=4, max_wait_s=10.0), clock, cache_size=0,
+    )
+    plan = server.plan
+
+    class _Waits:
+        """A device result whose copy to the host takes 2 s."""
+
+        def __init__(self, a):
+            self.a = a
+
+        def __array__(self, dtype=None, copy=None):
+            clock.tick(1.0)
+            return np.asarray(self.a, dtype)
+
+    class _Plan:
+        config = plan.config
+
+        def retrieve_batch(self, qd, md):
+            clock.tick(0.25)  # host time of the enqueue
+            res = plan.retrieve_batch(qd, md)
+            return res._replace(
+                scores=_Waits(res.scores), doc_ids=_Waits(res.doc_ids)
+            )
+
+    server.plan = _Plan()
+    r = [server.submit(q[0], qmask[0])]
+    clock.tick(0.5)
+    r.append(server.submit(q[1], qmask[1]))
+    clock.tick(0.125)
+    assert server.step(force=True) == 2
+    st = server.stats
+    assert st["queue_wait_us"] == 625_000 + 125_000
+    assert st["step_host_us"] == 250_000
+    # An empty step adds its (zero) host time and no wait.
+    assert server.step(force=True) == 0
+    assert server.stats["step_host_us"] == 250_000
+    assert all(server.poll(i) is not None for i in r)
 
 
 def test_scheduler_stats_property_reconstruction():
@@ -486,32 +685,6 @@ def test_ops_probe_rejects_reference_fallback(setup):
             nbits=idx.nbits, dim=idx.dim, cap=idx.cap,
             n_tokens=idx.n_tokens, use_kernel=False, probe="dma",
         )
-
-
-def test_kernel_dma_compute_split_reports(setup):
-    """The split helper returns either {} (config can't take the kernel
-    path) or the full probe field set with sane relations."""
-    from repro.core import engine
-
-    _, idx, q, qmask, _ = setup
-    cfg = WarpSearchConfig(
-        nprobe=8, k=5, t_prime=400, gather="fused", executor="kernel",
-        layout="ragged",
-    )
-    plan = Retriever.from_index(idx).plan(cfg)
-    sel = engine.select_probes(
-        plan._index, jnp.asarray(q[0], jnp.float32),
-        jnp.asarray(qmask[0], bool), plan.config, False,
-    )
-    out = engine.kernel_dma_compute_split(
-        plan._index, jnp.asarray(q[0], jnp.float32),
-        jnp.asarray(qmask[0], bool), sel, plan.config, warmup=1, iters=1,
-    )
-    if out:
-        assert set(out) >= {"kernel_full_ms", "dma_ms", "compute_ms",
-                            "overlap_frac", "probe_tile_c"}
-        assert 0.0 <= out["overlap_frac"] <= 1.0
-        assert out["dma_ms"] >= 0 and out["compute_ms"] >= 0
 
 
 # ---------------------------------------------------------------------------

@@ -86,6 +86,7 @@ def test_selective_sum_kernel_compiles(one_chip):
         _sds(one_chip, (Q, D, NB), jnp.float32),
     )
     assert "tpu_custom_call" in text
+    assert "%warp_decompress_score" in text  # the trace's stable op name
 
 
 @pytest.mark.parametrize("buffering", ["double", "single"])
@@ -102,6 +103,7 @@ def test_fused_dense_kernel_compiles(one_chip, buffering):
         _sds(one_chip, (Q, D, NB), jnp.float32),
     )
     assert "tpu_custom_call" in text
+    assert "%warp_fused_gather_score_dense" in text
 
 
 @pytest.mark.parametrize(
@@ -122,6 +124,7 @@ def test_ragged_kernel_compiles(one_chip, buffering, tile_c):
         _sds(one_chip, (Q, D, NB), jnp.float32),
     )
     assert text.count("tpu_custom_call") >= -(-w // MAX_WORKLIST_TILES)
+    assert "%warp_fused_gather_score_ragged" in text
 
 
 def _lifestyle_index(sharding) -> WarpIndex:
