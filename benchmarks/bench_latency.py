@@ -122,14 +122,11 @@ def _stage_fns(index, config):
 
     @jax.jit
     def stage_reduce(scores, doc_ids, valid, mse, qmask):
-        qm, p, cap = scores.shape
+        qm = scores.shape[0]
         valid = valid & qmask[:, None, None]
-        qtok = jnp.broadcast_to(
-            jnp.arange(qm, dtype=jnp.int32)[:, None, None], (qm, p, cap)
-        )
         return two_stage_reduce(
-            doc_ids.reshape(-1), qtok.reshape(-1), scores.reshape(-1),
-            valid.reshape(-1), mse, q_max=qm, k=config.k,
+            doc_ids.reshape(qm, -1), None, scores.reshape(qm, -1),
+            valid.reshape(qm, -1), mse, q_max=qm, k=config.k,
         )
 
     @functools.partial(jax.jit, static_argnames=("q_max",))

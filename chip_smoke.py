@@ -9,10 +9,7 @@ One chip: builds a LoTTE-Lifestyle-size index on the chip from ``--seed``
 Table 4, ``configs/warp_family.py``) through the chunked builder, stands
 up a ``RetrievalServer`` per kernel variant with
 ``WarpSearchConfig(nprobe=32, k=100, executor="kernel")``
-(``configs/warp_xtr.py``) and the serving launcher's
-``reduce_impl="segment"`` (the "scan" reduction's associative scans over
-the ~1M-entry candidate stream take the TPU compiler about two minutes
-per program), and answers the queries in each:
+(``configs/warp_xtr.py``), and answers the queries in each:
 
   materialize-dense   gather="materialize" (the default), dense layout
   fused-dense         gather="fused", dense layout
@@ -234,8 +231,7 @@ def serve_variant(name, retriever, q, qmask, warm_q, warm_mask, **strategy) -> V
     """Stand up a kernel-executor server for one variant and answer every
     query one request at a time (batch of one, result cache off)."""
     cfg = WarpSearchConfig(
-        nprobe=ARCH.nprobe, k=ARCH.k, executor="kernel",
-        reduce_impl="segment", **strategy,
+        nprobe=ARCH.nprobe, k=ARCH.k, executor="kernel", **strategy,
     )
     t0 = time.perf_counter()
     server = RetrievalServer(

@@ -65,17 +65,8 @@ def xtr_reference(
     doc_ids = token_doc_ids[idx]
     # XTR: m_i = lowest score retrieved for query token i.
     mse = jnp.where(qmask, vals[:, -1], 0.0)
-    qtok = jnp.broadcast_to(jnp.arange(qm, dtype=jnp.int32)[:, None], (qm, k_prime))
     valid = jnp.broadcast_to(qmask[:, None], (qm, k_prime))
-    return two_stage_reduce(
-        doc_ids.reshape(-1),
-        qtok.reshape(-1),
-        vals.reshape(-1),
-        valid.reshape(-1),
-        mse,
-        q_max=qm,
-        k=k,
-    )
+    return two_stage_reduce(doc_ids, None, vals, valid, mse, q_max=qm, k=k)
 
 
 @functools.partial(jax.jit, static_argnames=("config",))
@@ -105,14 +96,11 @@ def _plaid_impl(index: WarpIndex, q, qmask, config: WarpSearchConfig) -> TopKRes
     cand_scores = jnp.einsum("qnd,qd->qn", vecs, q).reshape(qm, p, cap)
 
     valid = valid & qmask[:, None, None]
-    qtok = jnp.broadcast_to(
-        jnp.arange(qm, dtype=jnp.int32)[:, None, None], (qm, p, cap)
-    )
     return two_stage_reduce(
-        doc_ids.reshape(-1),
-        qtok.reshape(-1),
-        cand_scores.reshape(-1),
-        valid.reshape(-1),
+        doc_ids.reshape(qm, -1),
+        None,
+        cand_scores.reshape(qm, -1),
+        valid.reshape(qm, -1),
         sel.mse,
         q_max=qm,
         k=config.k,

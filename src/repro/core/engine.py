@@ -514,9 +514,16 @@ def reduce_candidates(
     bound fewer than ``k`` slots on skew-free tiny indexes, so that
     layout pads the reduction to k (all-invalid slots). ``dfilter``'s
     doc mask (local id space of THIS index) masks filtered documents to
-    -inf before top-k — the exactness point of the filter pushdown. Its
-    ops carry the ``warp.reduce`` scope."""
+    -inf before top-k — the exactness point of the filter pushdown. The
+    dense stream is in [Q, nprobe, cap] order, so it goes in as one row
+    per query token and the imputation fold is a broadcast. Its ops carry
+    the ``warp.reduce`` scope."""
     with jax.named_scope(SCOPE_REDUCE):
+        ragged = config.layout == "ragged"
+        if not ragged:
+            doc_ids, scores, valid = (
+                a.reshape(q_max, -1) for a in (doc_ids, scores, valid)
+            )
         return two_stage_reduce(
             doc_ids,
             qtok,
@@ -526,9 +533,8 @@ def reduce_candidates(
             dfilter.doc_mask if dfilter is not None else None,
             q_max=q_max,
             k=config.k,
-            impl=config.reduce_impl,
             n_docs=index.n_docs or None,
-            pad_to_k=config.layout == "ragged",
+            pad_to_k=ragged,
         )
 
 

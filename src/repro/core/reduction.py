@@ -1,25 +1,28 @@
 """Two-stage reduction (paper §4.5), TPU-shaped.
 
 The paper merges per-(query-token, cluster) "strides" with a binary tree of
-sorted-run merges in C++. On TPU the same computation maps onto one global
-``lax.sort`` plus two segmented scans:
+sorted-run merges in C++. On TPU the same computation is one ``lax.sort``
+and one segmented sum, with no scatter and no gather over the candidate
+stream (the TPU serializes indexed access):
 
-  stage 1 (token-level): sort all candidate entries by the composite key
-      ``doc_id * Q + qtoken``; an inclusive segmented *max* scan computes,
-      at each run end, max over retrieved scores of that (doc, qtoken) —
-      exactly the implicit score-matrix fill of Eq. (1)'s alignment term.
-      (The paper's "inner-cluster max during decompression" special case is
-      subsumed: all duplicates collapse in one pass.)
+  imputation fold: Eq. (8) with missing-similarity imputation is
+          S_d = sum_i m_i + sum_{(i,d) present} max(score - m_i)
+      so each score is shifted by its query token's ``m_i`` before the
+      sort; float subtraction is monotone, so the max of the shifted
+      scores is the shifted max, bit for bit.
 
-  stage 2 (document-level): the row-wise sum with missing-similarity
-      imputation uses the identity
-          S_d = sum_i m_i + sum_{(i,d) present} (max_score_{i,d} - m_i)
-      so a segmented *sum* scan over doc runs of the adjusted run-end
-      values, plus one constant, realizes Eq. (8) without materializing the
-      score matrix — this is the paper's prefix-sum trick in TPU form.
+  stage 1 (token-level): sort by the composite key ``doc_id * Q + qtoken``
+      with the shifted score as the second key, so each (doc, qtoken) run
+      ends at its max — Eq. (1)'s implicit score-matrix fill, duplicates
+      included (the paper's inner-cluster max is subsumed).
+
+  stage 2 (document-level): a segmented inclusive sum of the run-end
+      values over each document, read at its last entry, plus the constant
+      ``sum_i m_i`` — the paper's prefix-sum trick (``_document_sums``).
 
 Padding entries carry key == SENTINEL and sort to the back. Top-k runs over
-run-end positions only (others are -inf).
+document-end positions only (others are -inf). ``WarpSearchConfig.reduce_impl``
+selects nothing: "scan" and "segment" both run this one reduction.
 """
 
 from __future__ import annotations
@@ -40,29 +43,38 @@ class TopKResult(NamedTuple):
     doc_ids: jax.Array  # i32[k], -1 padded
 
 
-def _segmented_scan(op, flags: jax.Array, values: jax.Array) -> jax.Array:
-    """Inclusive segmented scan; segment starts where ``flags`` is True."""
-
-    def combine(a, b):
-        fa, va = a
-        fb, vb = b
-        return fa | fb, jnp.where(fb, vb, op(va, vb))
-
-    _, out = jax.lax.associative_scan(combine, (flags, values))
-    return out
-
-
 def composite_key_fits_int32(n_docs: int, q_max: int) -> bool:
     """Whether ``doc_id * q_max + qtok`` stays below the int32 sentinel."""
     return (n_docs - 1) * q_max + (q_max - 1) < int(KEY_SENTINEL)
 
 
-@functools.partial(
-    jax.jit, static_argnames=("q_max", "k", "impl", "n_docs", "pad_to_k")
-)
+def _shift(x: jax.Array, d: int) -> jax.Array:
+    """``x`` moved ``d`` places toward the end, zeros shifted in."""
+    return jax.lax.pad(x, jnp.zeros((), x.dtype), [(d, -d, 0)])
+
+
+def _ends(x: jax.Array) -> jax.Array:
+    """True where the next entry differs, and at the last entry."""
+    return jnp.concatenate([x[:-1] != x[1:], jnp.ones((1,), bool)])
+
+
+def _document_sums(docid: jax.Array, vals: jax.Array) -> jax.Array:
+    """Inclusive sum of ``vals`` over each run of equal ``docid``: a
+    Hillis–Steele scan of ⌈log2 n⌉ contiguous shifts (after offset ``d``,
+    entry i sums (i - 2d, i] of its document, documents being contiguous).
+    Its tree order keeps the float error at O(log n) ulps, where a global
+    cumsum less its value at the document start would cancel."""
+    d = 1
+    while d < vals.shape[0]:
+        vals = vals + jnp.where(_shift(docid, d) == docid, _shift(vals, d), 0.0)
+        d *= 2
+    return vals
+
+
+@functools.partial(jax.jit, static_argnames=("q_max", "k", "n_docs", "pad_to_k"))
 def two_stage_reduce(
     doc_ids: jax.Array,
-    qtok_ids: jax.Array,
+    qtok_ids: jax.Array | None,
     scores: jax.Array,
     valid: jax.Array,
     mse: jax.Array,
@@ -70,16 +82,14 @@ def two_stage_reduce(
     *,
     q_max: int,
     k: int,
-    impl: str = "scan",
     n_docs: int | None = None,
     pad_to_k: bool = False,
 ) -> TopKResult:
-    """Reduce flat candidate entries to top-k document scores.
+    """Reduce candidate entries to top-k document scores.
 
-    doc_ids:  i32[N] candidate document ids.
-    qtok_ids: i32[N] query-token id of each candidate.
-    scores:   f32[N] token-level scores (centroid + selective residual sum).
-    valid:    bool[N] padding / masked-query-token indicator.
+    doc_ids, qtok_ids, scores, valid: each candidate's i32 document id,
+              i32 query token, f32 token-level score (centroid + selective
+              residual sum) and bool padding / masked-token indicator.
     mse:      f32[q_max] missing similarity estimates (0 at masked tokens).
     doc_mask: optional bool[n_docs] survivor bitmap (see
               ``core/docfilter.py``): filtered documents' totals are
@@ -87,26 +97,34 @@ def two_stage_reduce(
               never depends on which candidates survive, masking here is
               exact — surviving documents keep bit-identical scores.
 
-    impl: "scan" — tuple segmented scans (baseline; O(log N) full passes);
-          "segment" — cumsum run indices + segment_max/segment_sum scatters
-          (§Perf hillclimb: ~3x fewer memory passes on TPU).
+    Entries come flat, [N] (ragged worklist slots, whose query token is
+    data: ``mse`` is gathered per entry), or as [q_max, M] rows holding
+    query token i's candidates in row i (dense [Q, P, cap] stages:
+    ``qtok_ids`` may be None and the imputation fold is a broadcast).
 
     The fast path sorts by the int32 composite key ``doc_id * q_max + qtok``
     which requires ``(n_docs - 1) * q_max + q_max - 1 < int32 max``. Pass
     ``n_docs`` to make that precondition *checked*: when the composite
     would overflow, the reduction automatically switches to a lexicographic
-    two-key sort (``lax.sort(..., num_keys=2)``) that never forms the
-    product, at the cost of one extra sort operand. Without ``n_docs`` the
-    precondition is the caller's responsibility, as before.
+    (doc, qtok) sort that never forms the product, at the cost of one
+    extra sort operand. Without ``n_docs`` the precondition is the
+    caller's responsibility, as before.
 
-    The entries come in flat — dense callers reshape their [Q, P, cap]
-    stages, ragged callers feed worklist slots directly (the sort N *is*
-    ``n``, so a tighter candidate layout shrinks the dominant
-    ``lax.sort``). A ragged worklist bound may be smaller than ``k`` on
-    skew-free tiny indexes even though the (padded) dense pool is not;
-    ``pad_to_k`` appends invalid entries up to ``k`` in that case instead
-    of raising, preserving the -inf/-1-padded contract.
+    A ragged worklist bound may be smaller than ``k`` on skew-free tiny
+    indexes even though the (padded) dense pool is not; ``pad_to_k``
+    appends invalid entries up to ``k`` in that case instead of raising,
+    preserving the -inf/-1-padded contract.
     """
+    if scores.ndim == 2:
+        qtok_ids = jnp.broadcast_to(
+            jnp.arange(q_max, dtype=jnp.int32)[:, None], scores.shape
+        )
+        adj = scores - mse[:, None]
+        doc_ids, qtok_ids, adj, valid = (
+            a.reshape(-1) for a in (doc_ids, qtok_ids, adj, valid)
+        )
+    else:
+        adj = scores - mse[qtok_ids]
     n = doc_ids.shape[0]
     if k > n:
         if not pad_to_k:
@@ -117,69 +135,42 @@ def two_stage_reduce(
         pad = k - n
         doc_ids = jnp.pad(doc_ids, (0, pad))
         qtok_ids = jnp.pad(qtok_ids, (0, pad))
-        scores = jnp.pad(scores, (0, pad))
+        adj = jnp.pad(adj, (0, pad))
         valid = jnp.pad(valid, (0, pad))  # False: sorts to the back
         n = k
 
-    wide = n_docs is not None and not composite_key_fits_int32(n_docs, q_max)
-    if wide:
+    if n_docs is not None and not composite_key_fits_int32(n_docs, q_max):
         # Composite doc_id * q_max + qtok would overflow int32 (int64 is
         # unavailable without jax_enable_x64): sort by (doc, qtok) pair.
         dkey = jnp.where(valid, doc_ids, KEY_SENTINEL).astype(jnp.int32)
         qkey = jnp.where(valid, qtok_ids, KEY_SENTINEL).astype(jnp.int32)
-        dkey_s, qkey_s, scores_sorted = jax.lax.sort(
-            (dkey, qkey, scores), num_keys=2
+        docid, qkey_s, adj_sorted = jax.lax.sort(
+            (dkey, qkey, adj), num_keys=3, is_stable=False
         )
-        valid_sorted = dkey_s != KEY_SENTINEL
-        qtok = jnp.where(valid_sorted, qkey_s, 0)
-        # dkey_s already holds KEY_SENTINEL at invalid rows, which cannot
-        # collide with a representable doc id.
-        docid = dkey_s
-        same_prev = (dkey_s[1:] == dkey_s[:-1]) & (qkey_s[1:] == qkey_s[:-1])
-        false1 = jnp.zeros((1,), bool)
-        run_start = jnp.concatenate([~false1, ~same_prev])
-        run_end = jnp.concatenate([~same_prev, ~false1])
+        # docid holds KEY_SENTINEL at invalid rows, which cannot collide
+        # with a representable doc id.
+        run_end = _ends(docid) | _ends(qkey_s)
     else:
         key = jnp.where(
             valid, doc_ids * q_max + qtok_ids, KEY_SENTINEL
         ).astype(jnp.int32)
-        key_sorted, scores_sorted = jax.lax.sort((key, scores), num_keys=1)
-
-        valid_sorted = key_sorted != KEY_SENTINEL
-        qtok = jnp.where(valid_sorted, key_sorted % q_max, 0)
-        # Invalid rows get KEY_SENTINEL (not a representable doc id) so a
+        # Every operand is a key, so stability cannot change the result.
+        key_sorted, adj_sorted = jax.lax.sort((key, adj), num_keys=2, is_stable=False)
+        # Invalid rows keep KEY_SENTINEL (not a representable doc id) so a
         # real document adjacent to the padding block never merges with it.
-        docid = jnp.where(valid_sorted, key_sorted // q_max, KEY_SENTINEL)
+        docid = jnp.where(key_sorted != KEY_SENTINEL, key_sorted // q_max, KEY_SENTINEL)
+        run_end = _ends(key_sorted)
 
-        prev_key = jnp.concatenate([jnp.full((1,), -1, jnp.int32), key_sorted[:-1]])
-        next_key = jnp.concatenate([key_sorted[1:], jnp.full((1,), -2, jnp.int32)])
-        run_start = key_sorted != prev_key
-        run_end = key_sorted != next_key
-
-    prev_doc = jnp.concatenate([jnp.full((1,), -1, jnp.int32), docid[:-1]])
-    next_doc = jnp.concatenate([docid[1:], jnp.full((1,), -2, jnp.int32)])
-    doc_start = docid != prev_doc
-    doc_end = (docid != next_doc) & valid_sorted
-
-    if impl == "segment":
-        run_idx = jnp.cumsum(run_start.astype(jnp.int32)) - 1
-        run_max = jax.ops.segment_max(scores_sorted, run_idx, num_segments=n)
-        adj = jnp.where(run_end & valid_sorted, run_max[run_idx] - mse[qtok], 0.0)
-        doc_idx = jnp.cumsum(doc_start.astype(jnp.int32)) - 1
-        doc_sum = jax.ops.segment_sum(adj, doc_idx, num_segments=n)
-        total = doc_sum[doc_idx] + jnp.sum(mse)
-    else:
-        runmax = _segmented_scan(jnp.maximum, run_start, scores_sorted)
-        adj = jnp.where(run_end & valid_sorted, runmax - mse[qtok], 0.0)
-        dsum = _segmented_scan(jnp.add, doc_start, adj)
-        total = dsum + jnp.sum(mse)
+    valid_sorted = docid != KEY_SENTINEL
+    doc_end = _ends(docid) & valid_sorted
+    total = _document_sums(docid, jnp.where(run_end & valid_sorted, adj_sorted, 0.0))
+    total = total + jnp.sum(mse)
 
     if doc_mask is not None:
-        # Filter pushdown endpoint: a filtered doc's run-end total becomes
-        # -inf, so it cannot enter top-k. Invalid rows carry KEY_SENTINEL
-        # doc ids — clip for the gather; doc_end is already False there.
-        survives = doc_mask[jnp.clip(docid, 0, doc_mask.shape[0] - 1)]
-        doc_end = doc_end & survives
+        # Filter pushdown endpoint: a filtered doc's total becomes -inf, so
+        # it cannot enter top-k. Invalid rows carry KEY_SENTINEL doc ids —
+        # clip for the gather; doc_end is already False there.
+        doc_end = doc_end & doc_mask[jnp.clip(docid, 0, doc_mask.shape[0] - 1)]
     final = jnp.where(doc_end, total, -jnp.inf)
     top_scores, top_idx = jax.lax.top_k(final, k)
     top_docs = jnp.where(

@@ -161,7 +161,9 @@ class WarpSearchConfig:
     layout: str = "dense"  # "dense" | "ragged" | "auto" (see core/worklist.py)
     tile_c: int | None = None  # candidate tile rows; None -> autotune/heuristic
     buffering: str = "auto"  # "auto" | "double" | "single" (kernel DMA schedule)
-    reduce_impl: str = "scan"  # "scan" | "segment" (see reduction.py)
+    # Accepted for existing callers; both spellings run the one reduction
+    # (core/reduction.py).
+    reduce_impl: str = "scan"  # "scan" | "segment"
     sum_impl: str = "gather"  # "gather" | "lut" (byte-LUT; see kernels/ref.py)
     # Resolved by engine.resolve_config / Retriever.plan (static per-qtoken
     # worklist tile bound + adaptive bucket ladder; tile_c provenance);

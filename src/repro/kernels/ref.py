@@ -19,7 +19,6 @@ __all__ = [
     "ragged_selective_sum",
     "ragged_selective_sum_lut",
     "embedding_bag",
-    "fused_reduce_scores",
     "fused_gather_score",
     "ragged_fused_gather_score",
     "segmented_ragged_gather_codes",
@@ -318,58 +317,3 @@ def embedding_bag(
     if weights is not None:
         rows = rows * weights[:, None]
     return jax.ops.segment_sum(rows, segment_ids, num_segments=num_segments)
-
-
-def fused_reduce_scores(
-    keys: jax.Array,
-    scores: jax.Array,
-    m_per_qtoken: jax.Array,
-    *,
-    q_max: int,
-    sentinel: int,
-) -> tuple[jax.Array, jax.Array]:
-    """Two-stage reduction over a *key-sorted* stream (paper §4.5).
-
-    keys:   i32[N] sorted ascending; key = doc_id * q_max + qtoken, or
-            ``sentinel`` for padding entries (sorted to the back).
-    scores: f32[N] candidate token scores aligned with keys.
-    m_per_qtoken: f32[q_max] missing-similarity estimates (0 for masked).
-
-    Returns (doc_score f32[N], is_doc_end bool[N]) where doc_score[i] holds
-    sum_q max-token-score adjusted by imputation *only* at positions where
-    ``is_doc_end`` — i.e. the last entry of each document run. The final
-    constant sum(m) is already added. Reference implementation: O(N) numpy
-    -style scans in jnp.
-    """
-    n = keys.shape[0]
-    valid = keys != sentinel
-    qtok = (keys % q_max).astype(jnp.int32)
-    docid = keys // q_max
-
-    prev_key = jnp.concatenate([jnp.full((1,), -1, keys.dtype), keys[:-1]])
-    next_key = jnp.concatenate([keys[1:], jnp.full((1,), -2, keys.dtype)])
-    run_start = keys != prev_key
-    run_end = keys != next_key
-
-    # Token-level: inclusive segmented max scan.
-    def seg_scan(op, flags, values):
-        def combine(a, b):
-            fa, va = a
-            fb, vb = b
-            return fa | fb, jnp.where(fb, vb, op(va, vb))
-
-        _, out = jax.lax.associative_scan(combine, (flags, values))
-        return out
-
-    runmax = seg_scan(jnp.maximum, run_start, scores)
-
-    adj = jnp.where(run_end & valid, runmax - m_per_qtoken[qtok], 0.0)
-
-    prev_doc = jnp.concatenate([jnp.full((1,), -1, docid.dtype), docid[:-1]])
-    next_doc = jnp.concatenate([docid[1:], jnp.full((1,), -2, docid.dtype)])
-    doc_start = docid != prev_doc
-    doc_end = (docid != next_doc) & valid
-
-    dsum = seg_scan(jnp.add, doc_start, adj)
-    total = dsum + jnp.sum(m_per_qtoken)
-    return jnp.where(doc_end, total, -jnp.inf), doc_end

@@ -21,8 +21,8 @@ that crosses segment boundaries is shared or additive:
 Hence segmented search returns the same documents as the single-segment
 index ``compact()`` produces by folding the deltas back into a fresh base,
 with scores equal up to floating-point summation order (the reduction's
-``associative_scan`` tree shape depends on the candidate-array length, so
-the last ulp can differ) — that identity is the subsystem's correctness
+document-sum tree depends on the candidate-array length, so the last ulp
+can differ) — that identity is the subsystem's correctness
 anchor (tests/test_segments.py).
 
 Execution layouts over base + deltas
@@ -690,7 +690,6 @@ def _make_segmented_ragged_fn(
             fv.doc_mask if fv is not None else None,
             q_max=qm,
             k=cfg.k,
-            impl=cfg.reduce_impl,
             n_docs=n_docs_total or None,
             pad_to_k=True,
         )

@@ -1,14 +1,20 @@
 """Two-stage reduction vs a dense numpy oracle (paper Eq. 1/6/7/8)."""
 
+import re
+from types import SimpleNamespace
+
+import jax
 import jax.numpy as jnp
 import numpy as np
 from hypothesis import given, settings
 from hypothesis import strategies as st
 
+from repro.core import engine
 from repro.core.reduction import (
     composite_key_fits_int32,
     two_stage_reduce,
 )
+from repro.core.types import WarpSearchConfig
 
 
 def _oracle(doc_ids, qtok_ids, scores, valid, mse, n_docs, q_max):
@@ -172,11 +178,28 @@ def test_wide_key_path_matches_fast_path(seed, n, n_docs, q_max):
         jnp.asarray(valid), jnp.asarray(mse),
     )
     a = two_stage_reduce(*args, q_max=q_max, k=4, n_docs=n_docs)
-    b = two_stage_reduce(*args, q_max=q_max, k=4, n_docs=2**31 - 1)
+    b = two_stage_reduce(*args, q_max=q_max, k=4, n_docs=2**31)
     np.testing.assert_allclose(
         np.asarray(a.scores), np.asarray(b.scores), rtol=1e-5, atol=1e-5
     )
     np.testing.assert_array_equal(np.asarray(a.doc_ids), np.asarray(b.doc_ids))
+
+
+def _assert_topk_matches_oracle(res, want, k, tol=1e-5):
+    """Top-k against the oracle's {doc: score}: each returned doc carries
+    its own oracle score, rank i carries the i-th best score (so ties may
+    come in either order), no doc twice, and -1/-inf padding past the
+    distinct documents."""
+    got_scores, got_docs = np.asarray(res.scores), np.asarray(res.doc_ids)
+    best = sorted(want.values(), reverse=True)
+    n_expect = min(k, len(want))
+    for i in range(n_expect):
+        assert got_docs[i] in want, got_docs[i]
+        np.testing.assert_allclose(got_scores[i], want[got_docs[i]], rtol=tol, atol=tol)
+        np.testing.assert_allclose(got_scores[i], best[i], rtol=tol, atol=tol)
+    assert len(set(got_docs[:n_expect].tolist())) == n_expect
+    assert np.all(got_docs[n_expect:] == -1)
+    assert np.all(got_scores[n_expect:] == -np.inf)
 
 
 @settings(max_examples=25, deadline=None)
@@ -187,41 +210,34 @@ def test_wide_key_path_matches_fast_path(seed, n, n_docs, q_max):
     q_max=st.integers(1, 6),
     wide=st.booleans(),
 )
-def test_segment_impl_parity_duplicate_heavy(seed, n, n_docs, q_max, wide):
-    """Ragged/duplicate-heavy parity between the two reduction impls,
-    covering BOTH sort paths: the int32 composite key and the wide two-key
-    lexicographic sort (``wide`` fakes a huge n_docs so the composite would
-    overflow — previously the segment impl had no parity test there).
+def test_duplicate_heavy_matches_oracle(seed, n, n_docs, q_max, wide):
+    """Ragged/duplicate-heavy streams against the dense oracle, covering
+    BOTH sort paths: the int32 composite key and the wide (doc, qtok)
+    lexicographic sort (``wide`` fakes an n_docs whose composite key
+    overflows for every q_max >= 1).
 
     Few docs + few qtokens over many entries maximizes duplicate
     (doc, qtok) runs — exactly what a ragged worklist produces when one
-    document's tokens span several probed clusters. Top-k doc ids must be
-    bit-identical; scores may differ by summation order only
-    (``segment_sum`` scatter-adds in index order, ``associative_scan``
-    combines as a tree), so a few float32 ulps.
+    document's tokens span several probed clusters — and quantizing a
+    third of the scores makes ties inside a run and between documents.
     """
     rng = np.random.default_rng(seed)
     doc_ids = rng.integers(0, n_docs, n).astype(np.int32)
     qtok_ids = rng.integers(0, q_max, n).astype(np.int32)
     scores = rng.standard_normal(n).astype(np.float32)
-    # Duplicate-heavy score ties too: quantize a third of the entries.
     ties = rng.random(n) < 0.33
     scores[ties] = np.round(scores[ties], 1)
     valid = rng.random(n) > 0.3
     mse = (rng.standard_normal(q_max) * 0.1).astype(np.float32)
-    nd = (2**31 - 1) if wide else n_docs
+    nd = 2**31 if wide else n_docs
     if wide:
         assert not composite_key_fits_int32(nd, q_max)
-    args = (
+    res = two_stage_reduce(
         jnp.asarray(doc_ids), jnp.asarray(qtok_ids), jnp.asarray(scores),
-        jnp.asarray(valid), jnp.asarray(mse),
+        jnp.asarray(valid), jnp.asarray(mse), q_max=q_max, k=4, n_docs=nd,
     )
-    a = two_stage_reduce(*args, q_max=q_max, k=4, impl="scan", n_docs=nd)
-    b = two_stage_reduce(*args, q_max=q_max, k=4, impl="segment", n_docs=nd)
-    np.testing.assert_array_equal(np.asarray(a.doc_ids), np.asarray(b.doc_ids))
-    np.testing.assert_allclose(
-        np.asarray(a.scores), np.asarray(b.scores), rtol=0, atol=4e-6
-    )
+    want = _oracle(doc_ids, qtok_ids, scores, valid, mse, n_docs, q_max)
+    _assert_topk_matches_oracle(res, want, 4)
 
 
 def test_pad_to_k_pads_short_candidate_streams():
@@ -246,23 +262,62 @@ def test_pad_to_k_pads_short_candidate_streams():
 @settings(max_examples=25, deadline=None)
 @given(
     seed=st.integers(0, 2**31 - 1),
-    n=st.integers(4, 200),
+    m=st.integers(1, 50),
     n_docs=st.integers(1, 30),
     q_max=st.integers(1, 8),
 )
-def test_segment_impl_matches_scan_impl(seed, n, n_docs, q_max):
-    """§Perf variant ("segment") must be bit-compatible with baseline."""
+def test_dense_rows_match_oracle(seed, m, n_docs, q_max):
+    """The dense form — [q_max, M] rows, row i holding query token i's
+    candidates, imputation folded in by broadcast — against the oracle,
+    and bit-identical to the same stream passed flat with its qtok ids."""
     rng = np.random.default_rng(seed)
-    doc_ids = rng.integers(0, n_docs, n).astype(np.int32)
-    qtok_ids = rng.integers(0, q_max, n).astype(np.int32)
-    scores = rng.standard_normal(n).astype(np.float32)
-    valid = rng.random(n) > 0.2
+    doc_ids = rng.integers(0, n_docs, (q_max, m)).astype(np.int32)
+    qtok_ids = np.broadcast_to(np.arange(q_max, dtype=np.int32)[:, None], (q_max, m))
+    scores = rng.standard_normal((q_max, m)).astype(np.float32)
+    valid = rng.random((q_max, m)) > 0.2
     mse = (rng.standard_normal(q_max) * 0.1).astype(np.float32)
-    args = (
-        jnp.asarray(doc_ids), jnp.asarray(qtok_ids), jnp.asarray(scores),
-        jnp.asarray(valid), jnp.asarray(mse),
+    k = min(4, q_max * m)
+    rows = two_stage_reduce(
+        jnp.asarray(doc_ids), None, jnp.asarray(scores), jnp.asarray(valid),
+        jnp.asarray(mse), q_max=q_max, k=k,
     )
-    a = two_stage_reduce(*args, q_max=q_max, k=4, impl="scan")
-    b = two_stage_reduce(*args, q_max=q_max, k=4, impl="segment")
-    np.testing.assert_allclose(np.asarray(a.scores), np.asarray(b.scores), rtol=1e-5, atol=1e-5)
-    np.testing.assert_array_equal(np.asarray(a.doc_ids), np.asarray(b.doc_ids))
+    flat = two_stage_reduce(
+        *(jnp.asarray(a.reshape(-1)) for a in (doc_ids, qtok_ids, scores, valid)),
+        jnp.asarray(mse), q_max=q_max, k=k,
+    )
+    want = _oracle(
+        doc_ids.reshape(-1), qtok_ids.reshape(-1), scores.reshape(-1),
+        valid.reshape(-1), mse, n_docs, q_max,
+    )
+    _assert_topk_matches_oracle(rows, want, k)
+    np.testing.assert_array_equal(np.asarray(rows.doc_ids), np.asarray(flat.doc_ids))
+    np.testing.assert_array_equal(np.asarray(rows.scores), np.asarray(flat.scores))
+
+
+def test_dense_reduction_has_no_stream_scatter_or_gather():
+    """The batch program's reduction (``engine.reduce_candidates`` under
+    ``vmap``, dense layout) lowers with no scatter and no gather whose
+    output has the candidate stream's length: the TPU serializes indexed
+    access, and the reduction needs none but the top-k's doc ids."""
+    b, q_max, m, k = 3, 4, 37, 5
+    n = q_max * m
+    index = SimpleNamespace(n_docs=1000)
+    cfg = WarpSearchConfig(k=k, layout="dense")
+
+    def reduce_one(doc_ids, qtok, scores, valid, mse):
+        return engine.reduce_candidates(
+            index, doc_ids, qtok, scores, valid, mse, cfg, q_max=q_max
+        )
+
+    stream = lambda dt: jax.ShapeDtypeStruct((b, n), dt)  # noqa: E731
+    text = jax.jit(jax.vmap(reduce_one)).lower(
+        stream(jnp.int32), stream(jnp.int32), stream(jnp.float32),
+        stream(jnp.bool_), jax.ShapeDtypeStruct((b, q_max), jnp.float32),
+    ).as_text()
+    assert "stablehlo.sort" in text
+    assert "stablehlo.scatter" not in text
+    gathers = [line for line in text.splitlines() if '"stablehlo.gather"' in line]
+    assert gathers, "the top-k's doc ids are one gather of k per query"
+    for line in gathers:
+        out_dims = re.findall(r"\d+", line.rsplit("->", 1)[1])
+        assert str(n) not in out_dims, line.strip()
