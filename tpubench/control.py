@@ -31,7 +31,7 @@ import numpy as np  # noqa: E402
 from tpubench import compare, run, spec, traffic  # noqa: E402
 
 
-def control_gap(index, config: dict, queries) -> float:
+def control_gap(shards, config: dict, queries) -> float:
     """The bfloat16 reference in the program's place, against the float32
     reference."""
     import jax.numpy as jnp
@@ -40,42 +40,40 @@ def control_gap(index, config: dict, queries) -> float:
 
     k = config["k"]
     blank = [(None, np.full(k, -1, np.int32))] * len(queries)
-    low = reference.run_reference(index, config, queries, blank, dtype=jnp.bfloat16)
+    low = reference.run_reference(shards, config, queries, blank, dtype=jnp.bfloat16)
     served = [(s, d) for s, d, _ in low]
-    refs = reference.run_reference(index, config, queries, served)
+    refs = reference.run_reference(shards, config, queries, served)
     return compare.score_gap(served, refs)
 
 
 def readings(cell: spec.Cell, seed: int, seconds: float, with_control: bool, *, chip: bool = True) -> dict:
     import jax
 
-    from repro.core import WarpIndex
     from tpubench import checks, reference, synth
 
-    if chip:
-        checks.require_tpu(cell.chips)
+    devices = checks.require_tpu(cell.chips) if chip else jax.devices()[: cell.chips]
     c, t = cell.config, cell.traffic
-    index, sizes = synth.make_index(c, seed, WarpIndex)
+    shards, index, mesh = run.make_corpus(cell, seed, devices)
     qs, ms = synth.make_queries(
-        c, index, sizes, seed, traffic.pool_size(c, t, seconds), stream=0, active=t["active_tokens"]
+        c, shards, seed, traffic.pool_size(c, t, seconds), stream=0, active=t["active_tokens"]
     )
-    server = run.make_server(cell, index)
+    server = run.make_server(cell, index, mesh)
     window = run.drive(cell, server, qs, ms, seconds, False)
     checks.check_no_fallback(server)
-    del server
+    del server, index
     gc.collect()
     done = np.flatnonzero(window.counted & ~np.isnan(window.done))
     picked = compare.sample(done, run.CHECK_REQUESTS, np.random.default_rng([4, seed]))
     replies = [window.replies[j] for j in picked]
     queries = [(qs[window.pool_idx[j]], ms[window.pool_idx[j]]) for j in picked]
-    refs = reference.run_reference(index, c, queries, replies)
+    refs = reference.run_reference(shards, c, queries, replies)
     out = {
         "seed": seed, "compared": len(picked), "failed": window.n_failed,
         "program": compare.score_gap(replies, refs),
     }
     if with_control:
-        out["control"] = control_gap(index, c, queries)
-    del index
+        out["control"] = control_gap(shards, c, queries)
+    del shards
     jax.clear_caches()
     gc.collect()
     return out

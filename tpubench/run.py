@@ -3,7 +3,9 @@
 
     python tpubench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
-The run makes the cell's index on the device from ``--seed``, stands up
+The run makes the cell's index on its devices from ``--seed`` (one
+shard per chip: a ``WarpIndex`` on one, a document-sharded
+``ShardedWarpIndex`` over a ``("data",)`` mesh on several), stands up
 one ``RetrievalServer`` with the configuration's plan and the kernel
 executor, warms the one batch program its traffic uses, then drives the
 traffic for ``--seconds`` and checks a sample of the replies against the
@@ -63,7 +65,8 @@ class RunView:
     window: traffic.Window
     counters: dict  # server counters over the window
     trace: object | None  # trace_reduce.TraceSummary
-    work: dict  # roofline inputs: candidate tokens served, the chip's peaks
+    work: dict  # roofline inputs: candidate tokens served per chip, the chip's peaks
+    hlo_texts: tuple = ()  # optimized HLO of the programs loaded when the window closed
 
 
 def served_in_window(w: traffic.Window) -> float:
@@ -94,7 +97,25 @@ def end_to_end(name: str, window: traffic.Window, seconds: float, setup_s: float
     raise spec.SpecError(f"the harness computes no end-to-end metric {name!r}")
 
 
-def make_server(cell: spec.Cell, index):
+def make_corpus(cell: spec.Cell, seed: int, devices):
+    """The cell's corpus, one shard per device, made from ``seed``, and
+    the program's index over it: (shards, index, mesh). One shard is a
+    ``WarpIndex`` and no mesh; several are a ``ShardedWarpIndex`` over a
+    ``("data",)`` mesh of ``devices``."""
+    import jax
+
+    from repro.core import ShardedWarpIndex, WarpIndex
+    from tpubench import synth
+
+    c = cell.config
+    shards = synth.make_shards(c, seed, devices)
+    if len(shards) == 1:
+        return shards, synth.warp_index(c, shards[0], WarpIndex), None
+    mesh = jax.sharding.Mesh(np.asarray(devices), ("data",))
+    return shards, synth.sharded_index(c, shards, ShardedWarpIndex, mesh), mesh
+
+
+def make_server(cell: spec.Cell, index, mesh=None):
     from repro.core import Retriever, WarpSearchConfig
     from repro.serving import BatchPolicy, RetrievalServer
 
@@ -105,7 +126,7 @@ def make_server(cell: spec.Cell, index):
     )
     srv = c["serving"]
     return RetrievalServer(
-        Retriever.from_index(index), cfg,
+        Retriever.from_index(index, mesh=mesh), cfg,
         BatchPolicy(max_batch=srv["max_batch"], max_wait_s=srv["max_wait_s"]),
         cache_size=srv["cache_size"],
     )
@@ -121,23 +142,25 @@ def drive(cell: spec.Cell, server, qs, ms, seconds: float, trace: bool) -> traff
     raise spec.SpecError(f"traffic loop {t['loop']!r} is neither 'open' nor 'closed'")
 
 
-def check_replies(cell: spec.Cell, index, window: traffic.Window, qs, ms, seed: int) -> dict:
+def check_replies(cell: spec.Cell, shards, window: traffic.Window, qs, ms, seed: int) -> dict:
     from tpubench import reference
 
     done = np.flatnonzero(window.counted & ~np.isnan(window.done))
     picked = compare.sample(done, CHECK_REQUESTS, np.random.default_rng([4, seed]))
     replies = [window.replies[j] for j in picked]
     queries = [(qs[window.pool_idx[j]], ms[window.pool_idx[j]]) for j in picked]
-    refs = reference.run_reference(index, cell.config, queries, replies)
+    refs = reference.run_reference(shards, cell.config, queries, replies)
     return compare.checks(cell.config, compare.score_gap(replies, refs), window.n_failed)
 
 
-def candidate_tokens(cell: spec.Cell, index, window: traffic.Window, qs, ms) -> int:
+def candidate_tokens(cell: spec.Cell, shards, window: traffic.Window, qs, ms) -> int:
+    """Real candidate tokens of the requests the window served, over all
+    shards."""
     from tpubench import reference
 
     served = [j for _, _, got in window.steps for j in got]
     pool = np.unique(window.pool_idx[served]) if served else np.zeros(0, np.int64)
-    per_query = reference.probe_tokens(index, cell.config, qs[pool], ms[pool])
+    per_query = reference.probe_tokens(shards, cell.config, qs[pool], ms[pool])
     counts = dict(zip(pool.tolist(), per_query.tolist()))
     return int(sum(counts[int(window.pool_idx[j])] for j in served))
 
@@ -146,19 +169,25 @@ def counter_delta(before: dict, after: dict) -> dict:
     return {k: after[k] - before.get(k, 0) for k in after}
 
 
-def peak_hbm_bytes(dev) -> tuple[int, int, int]:
-    """The most HBM the run needs at once on ``dev``: the allocator's peak
-    of live buffers (the index, queries, replies) plus the largest scratch
-    space of a compiled program loaded on the device. The allocator does
-    not count a program's scratch (the gathered codes, the sort's buffers,
-    relayout copies), which the runtime sets aside for each execution.
-    Returns (total, buffers, scratch)."""
-    buffers = int((dev.memory_stats() or {}).get("peak_bytes_in_use", 0))
-    scratch = max(
-        (e.get_compiled_memory_stats().temp_size_in_bytes for e in dev.client.live_executables()),
-        default=0,
-    )
-    return buffers + scratch, buffers, scratch
+def peak_hbm_bytes(devices) -> tuple[int, int, int]:
+    """The most HBM the run needs at once on the fullest of ``devices``:
+    the allocator's peak of live buffers there (the index, queries,
+    replies) plus the largest scratch space of a compiled program loaded
+    there. The allocator does not count a program's scratch (the gathered
+    codes, the sort's buffers, relayout copies), which the runtime sets
+    aside for each execution. Returns (total, buffers, scratch) of that
+    device."""
+    loaded = [(e, e.local_devices()) for e in devices[0].client.live_executables()]
+
+    def on(dev):
+        buffers = int((dev.memory_stats() or {}).get("peak_bytes_in_use", 0))
+        scratch = max(
+            (e.get_compiled_memory_stats().temp_size_in_bytes for e, on_devs in loaded if dev in on_devs),
+            default=0,
+        )
+        return buffers + scratch, buffers, scratch
+
+    return max(on(dev) for dev in devices)
 
 
 class Pauses:
@@ -216,21 +245,20 @@ def window_health(w: traffic.Window, pauses: Pauses) -> str:
 def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool, *, chip: bool = True) -> dict:
     import jax
 
-    from repro.core import WarpIndex
-    from tpubench import checks, synth
+    from tpubench import checks, scopes, synth
 
     devices = checks.require_tpu(cell.chips) if chip else jax.devices()[: cell.chips]
     dev = devices[0]
     log(f"devices: {len(devices)} x {dev.device_kind} ({dev.platform})")
     c, t = cell.config, cell.traffic
-    index, sizes = synth.make_index(c, seed, WarpIndex)
+    shards, index, mesh = make_corpus(cell, seed, devices)
     n_pool = traffic.pool_size(c, t, seconds)
-    qs, ms = synth.make_queries(c, index, sizes, seed, n_pool, stream=0, active=t["active_tokens"])
+    qs, ms = synth.make_queries(c, shards, seed, n_pool, stream=0, active=t["active_tokens"])
     max_batch = c["serving"]["max_batch"]
-    warm_q, warm_m = synth.make_queries(c, index, sizes, seed, 2 * max_batch, stream=1, active=t["active_tokens"])
-    log(f"index: {c['n_tokens']} tokens, {c['n_docs']} docs, {c['n_centroids']} centroids, "
-        f"cap {index.cap}; pool of {n_pool} queries; {time.monotonic() - T_START:.1f} s")
-    server = make_server(cell, index)
+    warm_q, warm_m = synth.make_queries(c, shards, seed, 2 * max_batch, stream=1, active=t["active_tokens"])
+    log(f"index: {c['n_tokens']} tokens, {c['n_docs']} docs, {c['n_centroids']} centroids in "
+        f"{len(shards)} shard(s), cap {index.cap}; pool of {n_pool} queries; {time.monotonic() - T_START:.1f} s")
+    server = make_server(cell, index, mesh)
     checks.check_no_fallback(server)
     if chip:
         checks.check_kernel_in_program(server, warm_q[:max_batch], warm_m[:max_batch])
@@ -250,18 +278,24 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool, *, chip: b
         window = drive(cell, server, qs, ms, seconds, trace)
     if trace:
         jax.profiler.stop_trace()
-    peak_bytes, buffers, scratch = peak_hbm_bytes(dev)
+    peak_bytes, buffers, scratch = peak_hbm_bytes(devices)
     checks.check_no_fallback(server)
     counters = counter_delta(before, dict(server.stats))
     late = (window.sent - window.due)[window.counted] * 1e3
     log(f"window: {window.n_counted} requests, {len(window.steps)} batches, {counters}; "
         f"generator late p50 {stats.percentile(late, 50):.3f} ms, max {np.max(late, initial=0):.3f} ms")
     log(f"window health: {window_health(window, pauses)}")
-    log(f"HBM: {peak_bytes} bytes at the peak = {buffers} of live buffers + {scratch} of program scratch")
+    log(f"HBM: {peak_bytes} bytes at the peak of the fullest device = {buffers} of live buffers + "
+        f"{scratch} of program scratch; per device {[peak_hbm_bytes([d])[0] for d in devices]}")
+    # The stage readers join the trace to these programs; a plan's programs
+    # may go with the server.
+    hlo_texts = tuple(scopes.live_hlo_texts()) if trace else ()
     del server
     gc.collect()
 
-    checked = check_replies(cell, index, window, qs, ms, seed)
+    t_check = time.monotonic()
+    checked = check_replies(cell, shards, window, qs, ms, seed)
+    log(f"reference: {CHECK_REQUESTS} sampled replies checked in {time.monotonic() - t_check:.1f} s")
     result = {
         "correct": compare.passed(checked),
         "attempted": window.n_counted,
@@ -280,11 +314,11 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool, *, chip: b
         from tpubench import roofline, trace_reduce
 
         summary = _reduce_trace(trace_dir)
-        work = {
-            "candidate_tokens": candidate_tokens(cell, index, window, qs, ms),
+        work = {  # per chip, as the trace's device times are
+            "candidate_tokens": candidate_tokens(cell, shards, window, qs, ms) / len(devices),
             "peaks": roofline.peaks(dev.device_kind) if chip else None,
         }
-        view = RunView(cell, window, counters, summary, work)
+        view = RunView(cell, window, counters, summary, work, hlo_texts)
         for m in cell.per_layer:
             v = cell.readers[m.name](view)
             if v is not None:

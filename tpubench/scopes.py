@@ -5,7 +5,7 @@ The retrieve program gives every op of its three stages a named scope
 compiler keeps in the op's ``op_name`` metadata. The profiler's XLA Ops
 events do not carry that metadata: an event is named by its HLO
 instruction's text. So the join goes through the optimized HLO of the
-executables still loaded on the device after the window: each
+executables loaded on the devices when the window closed: each
 instruction's text gives a key, and its ``op_name`` gives the stage.
 
 - The key of an instruction is its name, result shape without layout,
@@ -161,7 +161,7 @@ def split(op_seconds: dict, hlo_texts) -> tuple[dict, dict]:
 
 def live_hlo_texts() -> list[str]:
     """Optimized HLO, with metadata, of every executable loaded on the
-    first device."""
+    devices of the first device's client: all of a host's chips."""
     import jax
 
     return [
@@ -171,18 +171,19 @@ def live_hlo_texts() -> list[str]:
     ]
 
 
-_LAST: list = [None, None]  # [trace, split] of the last trace read
+_LAST: list = [None, None]  # [run, split] of the last run read
 
 
 def stage_ms(run, stage: str) -> float | None:
     """Device time per dispatched batch of the ops under ``stage`` in a
-    traced run, or None: no trace, or no op under that scope (a program
-    without the scopes)."""
+    traced run, joined to the HLO of the programs loaded when its window
+    closed (``run.hlo_texts``), or None: no trace, or no op under that
+    scope (a program without the scopes)."""
     tr = run.trace
     if tr is None:
         return None
-    if _LAST[0] is not tr:
-        _LAST[:] = [tr, split(tr.op_seconds, live_hlo_texts())]
+    if _LAST[0] is not run:
+        _LAST[:] = [run, split(tr.op_seconds, run.hlo_texts)]
     sec = _LAST[1][0][stage]
     return tr.per_batch_ms(sec / tr.n_chips) if sec > 0 else None
 

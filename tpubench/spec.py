@@ -7,10 +7,11 @@ metric is read by ``metrics/<name>.py``, or, where no such file is
 there, by the reader of the quantity its name starts with: the part
 before its first dot. ``xla_ms.steady`` (moving ``p50_ms``) and
 ``xla_ms.offline`` (moving ``qps``) are one quantity, split by the
-end-to-end metric it moves, read by ``metrics/xla_ms.py``. A later
-change adds a
-configuration, a mix or a metric by adding files and entries, never by
-editing one that is there.
+end-to-end metric it moves, read by ``metrics/xla_ms.py``. A
+configuration may state ``shards``: its corpus is then document-sharded,
+one shard per chip, and a cell of it asks for as many chips. A later
+change adds a configuration, a mix or a metric by adding files and
+entries, never by editing one that is there.
 """
 
 from __future__ import annotations
@@ -92,6 +93,20 @@ def load_reader(path: Path) -> Callable[[Any], float | None]:
     return read
 
 
+def _check_shards(cell: str, chips: int, config: dict) -> None:
+    """A configuration's ``shards`` (one when absent) are document shards,
+    one per chip; ``n_tokens`` and ``n_centroids`` are the whole
+    corpus's and split evenly over them."""
+    shards = config.get("shards", 1)
+    if not isinstance(shards, int) or shards < 1:
+        raise SpecError(f"configuration {config.get('name')!r}: shards must be a positive integer, got {shards!r}")
+    if chips != shards:
+        raise SpecError(f"workload {cell} asks for {chips} chip(s) for {shards} shard(s); one shard per chip")
+    for key in ("n_tokens", "n_centroids"):
+        if shards > 1 and config[key] % shards:
+            raise SpecError(f"{key} {config[key]} does not split over {shards} shards")
+
+
 def load_cell(root: Path, name: str, bench_dir: Path = HERE) -> Cell:
     """Resolve cell ``name`` of ``root/BENCHMARK.json``. Traffic mixes and
     readers are looked up under ``bench_dir``; the configuration file is
@@ -105,6 +120,7 @@ def load_cell(root: Path, name: str, bench_dir: Path = HERE) -> Cell:
     if w["config"] not in configs:
         raise SpecError(f"workload {name} names unknown config {w['config']!r}")
     config = _read_json(root / configs[w["config"]]["file"])
+    _check_shards(name, int(w["chips"]), config)
     traffic = _read_json(bench_dir / "traffic" / f"{w['traffic']}.json")
     e2e = tuple(m for m in map(_metric, bench["end_to_end"]) if m.reported_in(name))
     per_layer = tuple(m for m in map(_metric, bench["per_layer"]) if m.reported_in(name))

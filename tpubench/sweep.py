@@ -43,22 +43,21 @@ def main(argv=None) -> int:
     ap.add_argument("--loads", default="0.6,0.8,0.9,1.0,1.1")
     args = ap.parse_args(argv)
     cell = spec.load_cell(ROOT, args.workload)
-    from repro.core import WarpIndex
     from repro.launch.compile_cache import setup_compile_cache
     from tpubench import checks, synth
 
     setup_compile_cache()
-    checks.require_tpu(cell.chips)
+    devices = checks.require_tpu(cell.chips)
     c, t = cell.config, cell.traffic
-    index, sizes = synth.make_index(c, args.seed, WarpIndex)
-    server = run.make_server(cell, index)
+    shards, index, mesh = run.make_corpus(cell, args.seed, devices)
+    server = run.make_server(cell, index, mesh)
     max_batch = c["serving"]["max_batch"]
     stream = 0
 
     def pool(n):
         nonlocal stream
         stream += 1
-        return synth.make_queries(c, index, sizes, args.seed, n, stream=stream, active=t["active_tokens"])
+        return synth.make_queries(c, shards, args.seed, n, stream=stream, active=t["active_tokens"])
 
     wq, wm = pool(2 * max_batch)
     for i in range(2 * max_batch):

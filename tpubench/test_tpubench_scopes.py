@@ -133,9 +133,10 @@ STAGE_METRICS = {
 }
 
 
-def test_stage_readers_on_a_synthetic_run(monkeypatch):
-    monkeypatch.setattr(scopes, "live_hlo_texts", lambda: [MODULE_A, MODULE_B])
-    run = RunView(cell=None, window=None, counters={}, trace=_trace(TRACE_OPS), work={})
+def test_stage_readers_on_a_synthetic_run():
+    run = RunView(
+        cell=None, window=None, counters={}, trace=_trace(TRACE_OPS), work={}, hlo_texts=(MODULE_A, MODULE_B)
+    )
     for name, ms in STAGE_METRICS.items():
         assert _read(name, run) == pytest.approx(ms)
     # The three stages and the unattributed rest add up to all device time.
@@ -144,13 +145,15 @@ def test_stage_readers_on_a_synthetic_run(monkeypatch):
     )
 
 
-def test_stage_readers_give_nothing_without_trace_or_scopes(monkeypatch):
-    monkeypatch.setattr(scopes, "live_hlo_texts", lambda: [MODULE_A.replace("warp.", "")])
-    no_trace = RunView(cell=None, window=None, counters={}, trace=None, work={})
-    unscoped = RunView(cell=None, window=None, counters={}, trace=_trace(TRACE_OPS), work={})
+def test_stage_readers_give_nothing_without_trace_or_scopes():
+    texts = (MODULE_A.replace("warp.", ""),)
+    no_trace = RunView(cell=None, window=None, counters={}, trace=None, work={}, hlo_texts=texts)
+    unscoped = RunView(cell=None, window=None, counters={}, trace=_trace(TRACE_OPS), work={}, hlo_texts=texts)
+    untexted = RunView(cell=None, window=None, counters={}, trace=_trace(TRACE_OPS), work={})
     for name in STAGE_METRICS:
         assert _read(name, no_trace) is None
         assert _read(name, unscoped) is None
+        assert _read(name, untexted) is None
 
 
 def test_counter_readers():

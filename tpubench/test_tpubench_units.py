@@ -3,9 +3,11 @@ synthesizer, the comparison, and the data-driven layout."""
 
 from __future__ import annotations
 
+import hashlib
 import json
 import re
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -27,25 +29,32 @@ class _Index:
         self.__dict__.update(kw)
 
 
+def _shards(config, seed, n_shards=1):
+    """A corpus of ``n_shards`` shards, all on the one CPU device."""
+    return synth.make_shards(dict(config, shards=n_shards), seed, jax.devices()[:1] * n_shards)
+
+
 @pytest.fixture(scope="module")
 def tiny_index():
     config = _tiny_config()
-    index, sizes = synth.make_index(config, 2**31 + 77, _Index)
-    return config, index, sizes
+    shards = _shards(config, 2**31 + 77)
+    return config, shards, synth.warp_index(config, shards[0], _Index)
 
 
-def test_counts_match_a_hand_count(tiny_index):
-    config, index, sizes = tiny_index
-    qs, ms = synth.make_queries(config, index, sizes, 5, 3, stream=0, active=6)
-    got = reference.probe_tokens(index, config, qs, ms, chunk=2)
-    cent = np.asarray(index.centroids)
+@pytest.mark.parametrize("n_shards", [1, 4])
+def test_counts_match_a_hand_count(n_shards):
+    config = _tiny_config()
+    shards = _shards(config, 2**31 + 77, n_shards)
+    qs, ms = synth.make_queries(config, shards, 5, 3, stream=0, active=6)
+    got = reference.probe_tokens(shards, config, qs, ms, chunk=2)
     want = []
     for q, m in zip(qs, ms):
         total = 0
-        for tok in q[m]:
-            s = cent @ tok
-            top = np.argsort(-s, kind="stable")[: config["nprobe"]]
-            total += int(sizes[top].sum())
+        for sh in shards:
+            cent = np.asarray(sh.centroids).reshape(-1, config["dim"])
+            for tok in q[m]:
+                top = np.argsort(-(cent @ tok), kind="stable")[: config["nprobe"]]
+                total += int(sh.host_sizes[top].sum())
         want.append(total)
     assert got.tolist() == want
     n = want[0]
@@ -58,28 +67,79 @@ def test_counts_match_a_hand_count(tiny_index):
         roofline.peaks("cpu")
 
 
-def test_index_synthesizer_writes_valid_csr(tiny_index):
-    config, index, sizes = tiny_index
-    n = config["n_tokens"]
-    offs = np.asarray(index.cluster_offsets)
-    sz = np.asarray(index.cluster_sizes)
-    assert offs[0] == 0 and offs[-1] == n and np.array_equal(np.diff(offs), sz)
-    assert np.array_equal(sz, sizes) and index.cap == sz.max() and (sz > 0).all()
-    docs = np.asarray(index.token_doc_ids)
-    assert docs.shape == (n,) and docs.min() >= 0 and docs.max() < config["n_docs"]
-    assert index.packed_codes.shape == (n, 64) and index.packed_codes.dtype == jnp.uint8
-    assert np.allclose(np.linalg.norm(np.asarray(index.centroids), axis=1), 1.0, atol=1e-5)
+@pytest.mark.parametrize("n_shards", [1, 4])
+def test_index_synthesizer_writes_valid_csr(n_shards):
+    config = _tiny_config()
+    shards = _shards(config, 2**31 + 77, n_shards)
+    n, c = config["n_tokens"] // n_shards, config["n_centroids"] // n_shards
+    lead = () if n_shards == 1 else (1,)
+    for s, sh in enumerate(shards):
+        offs = np.asarray(sh.offsets).reshape(-1)
+        sz = np.asarray(sh.sizes).reshape(-1)
+        assert offs[0] == 0 and offs[-1] == n and np.array_equal(np.diff(offs), sz) and len(sz) == c
+        assert np.array_equal(sz, sh.host_sizes) and sh.cap == sz.max() and (sz > 0).all()
+        # Every shard deals the one multiset of sizes, in an order of its own.
+        assert np.array_equal(np.sort(sz), np.sort(shards[0].host_sizes))
+        assert s == 0 or not np.array_equal(sz, shards[0].host_sizes)
+        # Doc ids are local to a contiguous range of n_docs // shards (+1).
+        assert sh.doc_start == s * config["n_docs"] // n_shards
+        assert sh.n_docs in (config["n_docs"] // n_shards, -(-config["n_docs"] // n_shards))
+        docs = np.asarray(sh.doc_ids)
+        assert docs.shape == lead + (n,) and docs.min() >= 0 and docs.max() < sh.n_docs
+        assert sh.codes.shape == lead + (n, 64) and sh.codes.dtype == jnp.uint8
+        cent = np.asarray(sh.centroids)
+        assert cent.shape == lead + (c, 128) and np.allclose(np.linalg.norm(cent, axis=-1), 1.0, atol=1e-5)
+    assert sum(sh.n_docs for sh in shards) == config["n_docs"]
+    if n_shards > 1:
+        assert not np.array_equal(np.asarray(shards[1].codes), np.asarray(shards[0].codes))
     # Every seed deals the same multiset of sizes.
-    other, _ = synth.make_index(config, 3, _Index)
-    assert np.array_equal(np.sort(np.asarray(other.cluster_sizes)), np.sort(sz))
-    assert other.cap == index.cap
+    other = _shards(config, 3, n_shards)
+    assert np.array_equal(np.sort(other[0].host_sizes), np.sort(shards[0].host_sizes))
+    assert other[0].cap == shards[0].cap
+
+
+def test_one_shard_is_the_index_the_benchmark_always_made(tiny_index):
+    """Bit for bit the arrays (and queries) the one-chip benchmark made
+    before corpora had shards, for the same seed: their SHA-256."""
+    config, shards, index = tiny_index
+    h = hashlib.sha256()
+    for name in ("centroids", "packed_codes", "token_doc_ids", "cluster_offsets", "cluster_sizes",
+                 "bucket_weights", "bucket_cutoffs"):
+        h.update(np.asarray(getattr(index, name)).tobytes())
+    assert h.hexdigest() == "9ac0e4163224655a1f9ae478f675578d2fae91f4699a669ee65124d136ca0b31"
+    assert index.cap == shards[0].cap and index.n_docs == config["n_docs"] and index.n_tokens == config["n_tokens"]
+    q, _ = synth.make_queries(config, shards, 5, 3, stream=0, active=6)
+    assert hashlib.sha256(q.tobytes()).hexdigest() == (
+        "28bfe7f7c5d5ac0e0cd8db8e9119e0239cd0af3fa593d600a682a0ae6f276a1f"
+    )
+
+
+def test_the_sharded_reference_of_one_shard_is_the_reference(tiny_index):
+    """The sharded reference's stages, run apart, give what the one-shard
+    program gives, on one shard."""
+    config, shards, _ = tiny_index
+    qs, ms = synth.make_queries(config, shards, 8, 4, stream=0, active=7)
+    static = dict(
+        nprobe=config["nprobe"], k=config["k"], k_impute=config["k_impute"], t_prime=config["t_prime"],
+        cap=shards[0].cap, nbits=config["nbits"], dtype=jnp.float32,
+    )
+    sh = shards[0]
+    for q, m in zip(qs, ms):
+        served = np.arange(config["k"], dtype=np.int32) * 7 - 1
+        whole = reference.reference_query(
+            sh.centroids, sh.codes, sh.doc_ids, sh.offsets, sh.sizes, sh.weights,
+            jnp.asarray(q), jnp.asarray(m), jnp.asarray(served), n_docs=sh.n_docs, **static,
+        )
+        staged = reference.sharded_query(shards, q, m, served, **static)
+        for a, b in zip(whole, staged):
+            np.testing.assert_array_equal(np.asarray(a), b)
 
 
 def test_generators_are_deterministic(tiny_index):
-    config, index, sizes = tiny_index
-    a = synth.make_queries(config, index, sizes, 9, 4, stream=0, active=8)
-    b = synth.make_queries(config, index, sizes, 9, 4, stream=0, active=8)
-    c = synth.make_queries(config, index, sizes, 10, 4, stream=0, active=8)
+    config, shards, _ = tiny_index
+    a = synth.make_queries(config, shards, 9, 4, stream=0, active=8)
+    b = synth.make_queries(config, shards, 9, 4, stream=0, active=8)
+    c = synth.make_queries(config, shards, 10, 4, stream=0, active=8)
     assert np.array_equal(a[0], b[0]) and np.array_equal(a[1], b[1])
     assert not np.array_equal(a[0], c[0])
     g1 = traffic.poisson_gaps(50.0, 1000, np.random.default_rng([3, 2**33 + 1]))
@@ -194,3 +254,25 @@ def test_benchmark_json_keeps_to_its_contract():
     for name in cells:
         cell = spec.load_cell(root, name)
         assert {m.name for m in cell.end_to_end} > {"setup_s"} and cell.per_layer
+
+
+@pytest.mark.parametrize(
+    "shards,chips,tweak",
+    [(4, 1, {}), (None, 4, {}), (4, 4, {"n_centroids": 30}), (4, 4, {"n_tokens": 3001}), (0, 0, {})],
+)
+def test_a_cell_takes_one_chip_per_shard(tmp_path, shards, chips, tweak):
+    """``shards`` splits ``n_tokens`` and ``n_centroids`` evenly, one
+    shard per chip; a cell on another number of chips is refused."""
+    cell = tiny_cell(tmp_path, "offline", shards=4)
+    assert cell.chips == 4 and cell.config["shards"] == 4
+    config_file = tmp_path / "configs" / "tiny.json"
+    config = dict(json.loads(config_file.read_text()), shards=shards, **tweak)
+    if shards is None:
+        del config["shards"]
+    config_file.write_text(json.dumps(config))
+    bench = json.loads((tmp_path / "BENCHMARK.json").read_text())
+    for w in bench["workloads"]:
+        w["chips"] = chips
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
+    with pytest.raises(spec.SpecError):
+        spec.load_cell(tmp_path, "tiny.offline")

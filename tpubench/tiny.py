@@ -18,7 +18,8 @@ TINY = {
 
 def tiny_cell(root: Path, loop: str = "offline", **overrides) -> spec.Cell:
     """Write a one-config benchmark under ``root`` and resolve its
-    ``loop`` cell (``steady`` or ``offline``) with 8 active tokens."""
+    ``loop`` cell (``steady`` or ``offline``) with 8 active tokens, on as
+    many chips as the configuration has ``shards``."""
     config = json.loads((spec.HERE / "configs" / "lotte-lifestyle.json").read_text())
     config.update(TINY, name="tiny", **overrides)
     (root / "configs").mkdir(parents=True, exist_ok=True)
@@ -26,7 +27,8 @@ def tiny_cell(root: Path, loop: str = "offline", **overrides) -> spec.Cell:
     bench = json.loads((spec.HERE.parent / "BENCHMARK.json").read_text())
     bench["configs"] = [{"name": "tiny", "source": "test", "file": "configs/tiny.json", "reduced": [], "why": "test"}]
     bench["workloads"] = [
-        {"name": f"tiny.{t}", "config": "tiny", "traffic": t, "chips": 1, "why": "test"}
+        {"name": f"tiny.{t}", "config": "tiny", "traffic": t, "chips": config.get("shards", 1),
+         "why": "test"}
         for t in ("steady", "offline")
     ]
     for m in bench["end_to_end"] + bench["per_layer"]:
